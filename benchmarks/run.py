@@ -397,6 +397,8 @@ def main() -> None:
                     help="wall-time regression tolerance factor for "
                          "--check-baseline (default 3x)")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     module_rows = []
     if not args.smoke:

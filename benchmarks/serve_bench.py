@@ -222,6 +222,8 @@ def main() -> None:
     ap.add_argument("--write-baseline", default=None, metavar="PATH")
     ap.add_argument("--tolerance", type=float, default=3.0)
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     suite = run_suite(args.smoke)
     print("backend,p50_ms,p99_ms,seq_qps,batched_qps,speedup,"

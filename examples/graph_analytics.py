@@ -7,15 +7,16 @@ reports per-query latency and the layout optimizer's decisions.
 
     PYTHONPATH=src python examples/graph_analytics.py [--nodes 5000]
 
-Pass ``--backend device`` (or set ``REPRO_ENGINE_BACKEND=device``) to run
-the whole query batch on the device-resident set store: trie levels are
+With no ``--backend`` the platform decides, as for ``Engine()``: the
+device backend on a TPU host, numpy elsewhere (``REPRO_ENGINE_BACKEND``
+overrides).  ``--backend device`` runs the whole query batch on the
+device-resident set store: trie levels are
 uploaded once, each attribute extension is a single fused device call,
 and the terminal-fold intersections dispatch to the layout-cohort Pallas
 kernels. The kernel-dispatch summary printed at the end shows which
 kernel handled each intersection.
 """
 import argparse
-import os
 import time
 
 import numpy as np
@@ -34,11 +35,10 @@ def main():
     ap.add_argument("--mean-deg", type=float, default=12)
     ap.add_argument("--exponent", type=float, default=1.9)
     ap.add_argument("--backend",
-                    default=os.environ.get("REPRO_ENGINE_BACKEND", "numpy"),
-                    choices=("numpy", "device"),
-                    help="execution backend for the query engine")
+                    default=None, choices=("numpy", "device"),
+                    help="execution backend for the query engine "
+                         "(default: chosen by the platform)")
     args = ap.parse_args()
-    print(f"== backend: {args.backend} ==")
 
     print("== build + preprocess ==")
     g = powerlaw_graph(args.nodes, args.mean_deg, args.exponent, seed=0)
@@ -47,11 +47,12 @@ def main():
     pruned = prune_symmetric(g)
 
     store = HybridSetStore.build(pruned,
-                                 word_kernel=as_word_kernel(interpret=True))
+                                 word_kernel=as_word_kernel())
     print("layout optimizer:", store.stats())
 
     print("\n== serve pattern queries (WCOJ engine) ==")
     eng = Engine(backend=args.backend)
+    print(f"== backend: {eng.backend.name} ==")
     src = np.repeat(np.arange(g.n), g.degrees)
     eng.load_edges("Edge", src, g.neighbors)
     psrc = np.repeat(np.arange(pruned.n), pruned.degrees)
@@ -96,13 +97,6 @@ def main():
         merged[k] = merged.get(k, 0) + v
     for key in sorted(merged):
         print(f"  {key:28s} {merged[key]}")
-
-    print("\n== MXU dense-cohort triangle count (beyond-paper path) ==")
-    from repro.kernels.triangle_mm.ops import densify_csr, triangle_count_dense
-    t0 = time.perf_counter()
-    dense = densify_csr(pruned.offsets, pruned.neighbors, pruned.n)
-    c = int(triangle_count_dense(dense, symmetric=False))
-    print(f"  triangle_mm: {c} in {(time.perf_counter()-t0)*1e3:.1f} ms")
 
 
 if __name__ == "__main__":

@@ -11,7 +11,10 @@ edges = [(0, 1), (1, 2), (0, 2), (1, 3), (2, 3)]
 src = np.array([u for u, v in edges] + [v for u, v in edges])
 dst = np.array([v for u, v in edges] + [u for u, v in edges])
 
+# no backend named: the platform decides — the device backend on a TPU
+# host, the numpy backend elsewhere (REPRO_ENGINE_BACKEND overrides)
 eng = Engine()
+print(f"default backend: {eng.backend.name}")
 eng.load_edges("Edge", src, dst)
 for alias in ("R", "S", "T"):
     eng.alias(alias, "Edge")
@@ -48,9 +51,10 @@ eng.alias("T2", "Edge")
 print(eng.explain("B(;w:long) :- R(x,y),S(y,z),T(x,z),U(x,a),R2(a,b),"
                   "S2(b,c),T2(a,c); w=<<COUNT(*)>>."))
 
-# 6. the device execution backend: trie levels live on device, and the
-# hot-path intersections run through the layout-cohort Pallas kernels.
-# Equivalent: REPRO_ENGINE_BACKEND=device python examples/quickstart.py
+# 6. the device execution backend, named explicitly: trie levels live on
+# device, and the hot-path intersections run through the layout-cohort
+# Pallas kernels (compiled on a TPU, interpret mode elsewhere).  On a TPU
+# host this is also what Engine() above chose.
 dev = Engine(backend="device")
 dev.load_edges("Edge", src, dst)
 for alias in ("R", "S", "T"):

@@ -20,7 +20,9 @@ GraphIt algorithm/backend split (Zhang et al. 2018):
 
 Backend selection: ``Engine(backend=...)`` accepts a backend instance or
 the names ``"numpy"`` / ``"device"``; when unset, the
-``REPRO_ENGINE_BACKEND`` environment variable decides (default numpy).
+``REPRO_ENGINE_BACKEND`` environment variable decides, and without it the
+platform does: ``"device"`` where JAX's default backend is a TPU,
+``"numpy"`` elsewhere.
 
 Every backend carries ``stats``, a flat counter recording which kernel
 handled each intersection (``intersect.*`` keys count pairs) and the
@@ -226,6 +228,10 @@ class DeviceBackend(ExecBackend):
                  uint_max_len: int = UINT_KERNEL_MAX_LEN,
                  pipeline: Optional[bool] = None):
         super().__init__()
+        # Pallas interpret mode, resolved ONCE for every kernel this
+        # backend launches: compiled on a TPU, interpreted elsewhere
+        interpret = (interpret_default() if interpret is None
+                     else bool(interpret))
         self._interpret = interpret
         self._word_kernel = as_word_kernel(interpret=interpret)
         self._materialize_kernel = as_materialize_kernel(interpret=interpret)
@@ -242,16 +248,14 @@ class DeviceBackend(ExecBackend):
         # one launch per attribute step (Engine(fused_bags=...)
         # overrides).
         self.fuse_bags = _env_on("REPRO_FUSED_BAG", True)
-        # Fill-stage kernel: "pallas" runs the frontier-fill kernel
-        # package per morsel chunk; REPRO_FRONTIER_FILL=jnp (or any
-        # falsey value) pins the plain-jnp reference path as the
-        # differential oracle.
-        fm = os.environ.get("REPRO_FRONTIER_FILL", "pallas")
-        fm = fm.strip().lower()
-        self.fill_mode = "jnp" if (fm in _FALSEY or fm == "jnp") \
-            else "pallas"
-        self._fill_interpret = (bool(interpret) if interpret is not None
-                                else interpret_default())
+        # Fill-stage path: the plain-jnp fill (kernels/frontier_fill/
+        # ref.py) is the default because it is what the TPU compiler
+        # accepts.  REPRO_FRONTIER_FILL=pallas opts into the frontier-fill
+        # Pallas kernel, which the TPU compiler refuses (it gathers with
+        # 1-D vector indices inside the kernel and stages whole trie
+        # levels in VMEM), so it runs only in interpret mode.
+        fm = os.environ.get("REPRO_FRONTIER_FILL", "jnp").strip().lower()
+        self.fill_mode = "pallas" if fm == "pallas" else "jnp"
         # compile-vs-steady wall split: trace keys seen once are charged
         # to compile wall, repeats to steady wall (informational only —
         # kept OUT of ``stats`` so the exact dispatch gates stay exact).
@@ -449,7 +453,7 @@ class DeviceBackend(ExecBackend):
             cap_in=state.cap, cap_out=int(cap_out), morsel=int(morsel),
             seed_d0=bool(cons[0][2]), probe_d0=probe_d0,
             sideways_bits=sideways_bits, fill_mode=self.fill_mode,
-            fill_interpret=self._fill_interpret)
+            fill_interpret=self._interpret)
 
         it = iter(carry_c)
         cols = {k: next(it) for k in col_keys}
@@ -565,13 +569,13 @@ class DeviceBackend(ExecBackend):
             self.audit_log.append(
                 ("bag", "bag", prog_t, audit_avals(tuple(arrays)),
                  audit_avals(cur_canon), audit_avals(ann),
-                 self.fill_mode, self._fill_interpret))
+                 self.fill_mode, self._interpret))
         (count, overflow, morsels, lcounts, needs, cols, cursors,
          ann_o) = self._timed(
             ("bag", prog_t, self.fill_mode),
             _bag_program, tuple(arrays), cur_canon, ann,
             prog=prog_t, fill_mode=self.fill_mode,
-            fill_interpret=self._fill_interpret)
+            fill_interpret=self._interpret)
         id_of = {v: k for k, v in canon.items()}
         lvars = [s[1] for s in prog_t if s[0] in ("extend", "fold")]
         evars = [s[1] for s in prog_t if s[0] == "extend"]
@@ -703,12 +707,12 @@ class DeviceBackend(ExecBackend):
             self.audit_log.append(
                 ("bag_batch", "bag_batch", prog_t,
                  audit_avals(tuple(arrays)), audit_avals(cur_canon),
-                 audit_avals(ann), int(b), self._fill_interpret))
+                 audit_avals(ann), int(b), self._interpret))
         (count, overflow, morsels, lcounts, needs, cols, cursors,
          ann_o) = self._timed(
             ("bag_batch", prog_t, int(b)),
             _bag_program_batch, tuple(arrays), cur_canon, ann,
-            prog=prog_t, fill_interpret=self._fill_interpret)
+            prog=prog_t, fill_interpret=self._interpret)
         id_of = {v: k for k, v in canon.items()}
         lvars = [s[1] for s in prog_t if s[0] in ("extend", "fold")]
         evars = [s[1] for s in prog_t if s[0] == "extend"]
@@ -864,8 +868,8 @@ def _bounds(values, offsets, cursor, cap_in, valid):
 def _pipeline_step(count, overflow, seed, probes, sideways, carry, *,
                    cap_in: int, cap_out: int, morsel: int,
                    seed_d0: bool, probe_d0: Tuple[bool, ...],
-                   sideways_bits: Tuple = (), fill_mode: str = "jnp",
-                   fill_interpret: bool = True):
+                   sideways_bits: Tuple, fill_mode: str,
+                   fill_interpret: bool):
     """Per-step (unfused) jitted wrapper around ``_extend_body`` — one
     launch per attribute extension.  Whole-bag fusion calls the body
     directly from ``_bag_program`` instead."""
@@ -890,10 +894,11 @@ def _extend_body(count, overflow, seed, probes, sideways, carry, *,
        check against the static capacity);
     3. fill: ``morsel``-sized chunks invert the offsets (searchsorted)
        to seed positions, gather values and probe every other atom with
-       the branch-free lockstep search — one ``frontier_fill`` Pallas
-       launch per chunk (``fill_mode="jnp"`` pins the bit-identical
-       plain-jnp reference), and oversized frontiers just spill to the
-       next chunk of the same loop instead of a host round-trip;
+       the branch-free lockstep search — the plain-jnp fill per chunk
+       (``fill_mode="pallas"`` swaps in the bit-identical
+       ``frontier_fill`` kernel, interpret mode only), and oversized
+       frontiers just spill to the next chunk of the same loop instead
+       of a host round-trip;
     4. compaction: scatter surviving rows to a dense prefix and gather
        the previous frontier's columns/cursors/annotation through them.
 
@@ -1277,11 +1282,14 @@ _DEFAULT: Optional[ExecBackend] = None
 
 def make_backend(spec=None) -> ExecBackend:
     """Resolve ``spec`` (instance | name | None) to a fresh backend.
-    ``None`` defers to ``REPRO_ENGINE_BACKEND`` (default "numpy")."""
+    ``None`` defers to ``REPRO_ENGINE_BACKEND``; unset, the platform
+    decides: "device" where JAX's default backend is a TPU, else
+    "numpy"."""
     if isinstance(spec, ExecBackend):
         return spec
     if spec is None:
-        spec = os.environ.get("REPRO_ENGINE_BACKEND", "numpy")
+        spec = os.environ.get("REPRO_ENGINE_BACKEND") or (
+            "device" if jax.default_backend() == "tpu" else "numpy")
     spec = str(spec).lower()
     if spec not in _BY_NAME:
         raise ValueError(f"unknown backend {spec!r}; "

@@ -20,8 +20,9 @@ SSSP), in which case only the delta relation is re-joined each round.
 ``Engine(backend="device")`` keeps trie levels device-resident, fuses
 each attribute extension into one device call, and dispatches
 terminal-fold intersections to the layout-cohort Pallas kernels. With no
-argument the ``REPRO_ENGINE_BACKEND`` environment variable decides
-(default numpy). One backend instance lives per Engine, so multi-rule and
+argument the ``REPRO_ENGINE_BACKEND`` environment variable decides, and
+without it the platform: the device backend on a TPU host, numpy
+elsewhere. One backend instance lives per Engine, so multi-rule and
 recursive programs reuse its device-resident uploads across rules and
 iterations; ``Engine.dispatch_summary()`` reports which kernel handled
 each intersection.

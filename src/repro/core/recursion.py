@@ -11,8 +11,10 @@ EmptyHeaded supports Kleene-star rules with two evaluation strategies:
 
 The shared primitive is the semiring SpMV ``y[u] = ⨁_v A(u,v) ⊗ x[v]`` — a
 one-step join-aggregate `Out(x) :- Edge(x,z), X(z)`. Its jitted form is used
-by the GNN substrate too; the PageRank inner loop can route through the
-ELL-blocked Pallas kernel (``repro.kernels.spmv_ell``).
+by the GNN substrate too and is the PageRank inner loop; a caller may
+inject another SpMV (e.g. the ELL-blocked Pallas kernel
+``repro.kernels.spmv_ell``, interpret mode only: the TPU compiler refuses
+its 1-D gather).
 """
 from __future__ import annotations
 
@@ -51,8 +53,7 @@ def semiring_spmv(sr: Semiring, n: int, row: jnp.ndarray, col: jnp.ndarray,
 
 # ---------------------------------------------------------------- pagerank
 def pagerank(csr: CSRGraph, iters: int = 5, damping: float = 0.85,
-             spmv_fn: Optional[Callable] = None,
-             backend=None) -> np.ndarray:
+             spmv_fn: Optional[Callable] = None) -> np.ndarray:
     """Paper Table 2 PageRank: naive recursion, fixed iteration count.
 
         N(;w)        :- Edge(x,y); w=<<COUNT(x)>>
@@ -61,13 +62,11 @@ def pagerank(csr: CSRGraph, iters: int = 5, damping: float = 0.85,
                                y=0.15+0.85*<<SUM(z)>>.
 
     The body is a (+,*) join-aggregate = SpMV with InvDeg folded into the
-    propagated value. ``spmv_fn`` lets benchmarks inject the Pallas ELL
-    kernel; default is the jitted segment-sum SpMV — except when this
-    fixpoint API is handed the device execution backend
-    (``core.backend.DeviceBackend``), in which case the ELL kernel is
-    selected automatically and the whole fixpoint stays on device inside
-    one ``fori_loop``. (The datalog engine's PageRank program evaluates
-    through general naive recursion and does not route here.)
+    propagated value, and the whole fixpoint stays on device inside one
+    ``fori_loop``. ``spmv_fn`` lets benchmarks inject another SpMV;
+    default is the jitted segment-sum SpMV. (The datalog engine's
+    PageRank program evaluates through general naive recursion and does
+    not route here.)
     """
     n = csr.n
     row = jnp.asarray(csr_row_ids(csr))
@@ -77,15 +76,6 @@ def pagerank(csr: CSRGraph, iters: int = 5, damping: float = 0.85,
 
     x = jnp.full((n,), 1.0 / n, dtype=jnp.float32)
     base = (1.0 - damping) / n
-
-    if spmv_fn is None and getattr(backend, "name", None) == "device":
-        from repro.kernels.spmv_ell.ops import csr_to_ell, spmv_ell
-        cols, vals = csr_to_ell(csr.offsets, csr.neighbors)
-        cols_d, vals_d = jnp.asarray(cols), jnp.asarray(vals)
-        backend.stats["spmv.ell_kernel"] += iters
-
-        def spmv_fn(x_scaled):
-            return spmv_ell(cols_d, vals_d, x_scaled)
 
     if spmv_fn is None:
         def spmv_fn(x_scaled):

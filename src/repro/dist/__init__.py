@@ -10,10 +10,5 @@ Three orthogonal pieces, each consumed by a different layer of the stack:
                 model functions run unsharded on the host CPU)
   fault         FaultPolicy + StepRunner: retry-on-transient-failure and
                 checkpoint cadence for the training loop
-
-``compat`` papers over jax 0.4.x vs 0.5.x API differences (AbstractMesh
-constructor signature, the ``jax.set_mesh`` context) and is imported for
-its side effects before anything else in the package.
 """
-from repro.dist import compat  # noqa: F401  (installs jax 0.4.x shims)
 from repro.dist.fault import FaultPolicy, StepRunner, TransientError  # noqa: F401

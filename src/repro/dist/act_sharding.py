@@ -26,7 +26,6 @@ import jax
 from jax._src import mesh as mesh_lib
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.dist import compat  # noqa: F401
 from repro.dist.sharding import assign_prefix
 
 # Non-batch logical activation axes -> candidate mesh axes.

@@ -32,8 +32,6 @@ from typing import Any, Dict, Mapping, Tuple
 import jax
 from jax.sharding import PartitionSpec as P
 
-from repro.dist import compat  # noqa: F401  (AbstractMesh signature shim)
-
 AxisEntry = Any  # None | str | tuple[str, ...]
 
 

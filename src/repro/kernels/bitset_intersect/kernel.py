@@ -14,6 +14,10 @@ TPU-idiomatic). Shapes:
 
   wa, wb : [P, W] uint32  (W = words per bitset block, padded to 128 lanes)
   out    : [P]    int32   |a_i & b_i| summed over the W axis
+
+The 1-D output block is 1024 rows: XLA tiles a rank-1 int32 array of
+that length as ``T(1024)``, and Mosaic refuses an output block whose
+layout does not match it (a 256-row block lowers as ``T(256)``).
 """
 from __future__ import annotations
 
@@ -42,7 +46,7 @@ def _kernel(wa_ref, wb_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def bitset_and_popcount_kernel(wa, wb, *, block_rows: int = 256,
+def bitset_and_popcount_kernel(wa, wb, *, block_rows: int = 1024,
                                interpret: bool = False):
     """``pallas_call`` wrapper; P padded to block_rows, W padded to LANE."""
     p, w = wa.shape

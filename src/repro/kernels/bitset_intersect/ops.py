@@ -14,7 +14,12 @@ import numpy as np
 from repro.kernels.bitset_intersect.kernel import bitset_and_popcount_kernel
 from repro.kernels.common import LANE, interpret_default, round_up
 
-_BLOCK_ROWS = 256
+_BLOCK_ROWS = 1024   # the kernel's 1-D output block (see kernel.py)
+# Block pairs per launch.  Each gathered row is padded to 128 lanes
+# (512 B whatever the block width), so a launch's two operands take
+# 2 x _MAX_PAIRS x 512 B = 1 GiB of HBM; an unchunked call at Kronecker
+# scale 17 (6.5e7 block pairs) would need 33 GiB and fail to allocate.
+_MAX_PAIRS = 1 << 20
 
 
 def bitset_and_popcount(words, pos_a, pos_b, *, interpret=None):
@@ -33,12 +38,18 @@ def bitset_and_popcount(words, pos_a, pos_b, *, interpret=None):
         return jnp.zeros((0,), jnp.int32)
     w = words.shape[1]
     wpad = round_up(max(w, LANE), LANE)
-    ppad = round_up(p, _BLOCK_ROWS)
-    wa = jnp.zeros((ppad, wpad), jnp.uint32).at[:p, :w].set(words[pos_a])
-    wb = jnp.zeros((ppad, wpad), jnp.uint32).at[:p, :w].set(words[pos_b])
-    out = bitset_and_popcount_kernel(wa, wb, block_rows=_BLOCK_ROWS,
-                                     interpret=interpret)
-    return out[:p]
+    outs = []
+    for lo in range(0, p, _MAX_PAIRS):
+        pa = pos_a[lo:lo + _MAX_PAIRS]
+        pb = pos_b[lo:lo + _MAX_PAIRS]
+        n = pa.shape[0]
+        ppad = round_up(n, _BLOCK_ROWS)
+        wa = jnp.zeros((ppad, wpad), jnp.uint32).at[:n, :w].set(words[pa])
+        wb = jnp.zeros((ppad, wpad), jnp.uint32).at[:n, :w].set(words[pb])
+        out = bitset_and_popcount_kernel(wa, wb, block_rows=_BLOCK_ROWS,
+                                         interpret=interpret)
+        outs.append(out[:n])
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs)
 
 
 def as_word_kernel(interpret=None):

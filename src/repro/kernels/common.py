@@ -1,9 +1,11 @@
 """Shared Pallas kernel utilities.
 
-Kernels target TPU (pl.pallas_call + BlockSpec VMEM tiling). This container
-is CPU-only, so ``interpret_default()`` flips every kernel into interpret
-mode, which executes the kernel body in Python for correctness validation
-against the pure-jnp oracles in each kernel's ``ref.py``.
+Kernels target TPU (pl.pallas_call + BlockSpec VMEM tiling) and are
+compiled there.  On any other JAX backend ``interpret_default()`` flips
+them into interpret mode, which executes the kernel body through the
+interpreter for correctness validation against the pure-jnp oracles in
+each kernel's ``ref.py`` — never for speed.  ``DeviceBackend`` resolves
+it once, and ``chip_smoke.py`` fails when it resolves to interpret.
 
 TPU tiling notes (v5e): int32/float32 native VREG tile is (8, 128)
 (sublane, lane); bf16 is (16, 128). Block shapes below are multiples of the

@@ -1,14 +1,14 @@
-"""Plain-jnp oracle for the frontier-fill kernel.
+"""Plain-jnp fill chunk: the engine's default fill stage and the oracle
+for the frontier-fill kernel.
 
-This is verbatim the PR 7 fill-chunk computation (the body of
-``backend._pipeline_step``'s morsel ``while_loop``): invert the
+The body of ``backend._extend_body``'s morsel ``while_loop``: invert the
 exclusive-scan offsets back to source frontier rows, gather the seed
 values, and probe every other constraining atom with the branch-free
-lockstep search.  All arithmetic is int32 and every comparison is
-integral, so the Pallas kernel's outputs must match this reference
-BIT-EXACTLY — ``kernel_check`` enforces equality, and the engine's
-``REPRO_FRONTIER_FILL=jnp`` escape hatch runs this path directly as the
-differential oracle.
+lockstep search.  It is plain XLA, which the TPU compiler accepts (the
+Pallas kernel in this package is refused there).  All arithmetic is
+int32 and every comparison is integral, so the Pallas kernel's outputs
+must match this path BIT-EXACTLY — ``kernel_check`` enforces equality,
+and ``REPRO_FRONTIER_FILL=pallas`` runs the kernel in its place.
 """
 from __future__ import annotations
 

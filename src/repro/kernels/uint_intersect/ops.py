@@ -10,10 +10,9 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.common import LANE, interpret_default, round_up
+from repro.kernels.common import (LANE, SUBLANE, interpret_default,
+                                  round_up)
 from repro.kernels.uint_intersect.kernel import uint_intersect_kernel
-
-_BLOCK_ROWS = 8
 
 
 def uint_intersect_count(a, b, *, interpret=None):
@@ -23,16 +22,20 @@ def uint_intersect_count(a, b, *, interpret=None):
     a = jnp.asarray(a, jnp.int32)
     b = jnp.asarray(b, jnp.int32)
     p, la = a.shape
+    lb = b.shape[1]
     if p == 0:
         return jnp.zeros((0,), jnp.int32)
-    ppad = round_up(p, _BLOCK_ROWS)
-    lbpad = round_up(max(b.shape[1], LANE), LANE)
-    lapad = round_up(max(la, LANE), LANE)
-    a2 = jnp.full((ppad, lapad), -1, jnp.int32).at[:p, :la].set(a)
-    b2 = jnp.full((ppad, lbpad), -1, jnp.int32).at[:p, :b.shape[1]].set(b)
-    out = uint_intersect_kernel(a2, b2, block_rows=_BLOCK_ROWS,
-                                lb_blk=LANE, interpret=interpret)
-    return out[:p]
+    # the kernel puts one pair per lane: transpose, pad pairs to LANE and
+    # set elements to the sublane tile (B to whole lb_blk blocks)
+    ppad = round_up(p, LANE)
+    lapad = round_up(max(la, 1), SUBLANE)
+    lb_blk = min(LANE, round_up(max(lb, 1), SUBLANE))
+    lbpad = round_up(max(lb, 1), lb_blk)
+    a_t = jnp.full((lapad, ppad), -1, jnp.int32).at[:la, :p].set(a.T)
+    b_t = jnp.full((lbpad, ppad), -1, jnp.int32).at[:lb, :p].set(b.T)
+    out = uint_intersect_kernel(a_t, b_t, lb_blk=lb_blk,
+                                interpret=interpret)
+    return out[0, :p]
 
 
 def intersect_count_csr(offsets, neighbors, u, v, *, interpret=None,

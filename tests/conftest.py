@@ -1,16 +1,8 @@
 """Shared fixtures. NOTE: no XLA_FLAGS here — smoke tests and benches see
 the 1 real CPU device; only launch/dryrun.py (a subprocess in tests) forces
 512 placeholder devices."""
-import os
-import sys
-
 import numpy as np
 import pytest
-
-try:
-    import hypothesis  # noqa: F401
-except ImportError:  # container image has no hypothesis; use the stub
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "_stubs"))
 
 
 def pytest_configure(config):

@@ -426,16 +426,20 @@ def test_fused_env_escape_hatch(monkeypatch):
 
 
 def test_frontier_fill_jnp_mode_parity(monkeypatch):
-    """REPRO_FRONTIER_FILL=jnp swaps the Pallas fill kernel for its jnp
-    reference inside the same traced program — bit-identical results."""
-    monkeypatch.setenv("REPRO_FRONTIER_FILL", "jnp")
+    """The jnp fill is the default fill stage (the one the TPU compiler
+    accepts); REPRO_FRONTIER_FILL=pallas swaps in the frontier-fill
+    kernel, in interpret mode, inside the same traced program — both are
+    bit-identical to the oracle."""
     src, dst, _ = random_undirected_graph(22, 0.3, 3)
     oracle = make_engine(src, dst, "numpy").query(PAPER_QUERIES["4clique"])
-    eng = make_engine(src, dst, "device")
-    assert eng.backend.fill_mode == "jnp"
-    res, d = sync_delta(eng, PAPER_QUERIES["4clique"])
-    assert_same_result(oracle, res)
-    assert d.get("extend.host_syncs", 0) == 0, d
+    for mode in ("jnp", "pallas"):
+        if mode == "pallas":
+            monkeypatch.setenv("REPRO_FRONTIER_FILL", "pallas")
+        eng = make_engine(src, dst, "device")
+        assert eng.backend.fill_mode == mode
+        res, d = sync_delta(eng, PAPER_QUERIES["4clique"])
+        assert_same_result(oracle, res)
+        assert d.get("extend.host_syncs", 0) == 0, d
 
 
 def test_wall_split_compile_then_steady():

@@ -90,9 +90,7 @@ def test_non_pow2_declared_capacity_rejected():
 def test_f64_leak_rejected_under_x64_trace():
     """A float64 compiled in under enable_x64 must be flagged when the
     program's own inputs never declared a 64-bit width."""
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with jax.enable_x64():
         closed = jax.make_jaxpr(
             lambda x: (x.astype(jnp.float64) * 2.0).sum())(
             jax.ShapeDtypeStruct((4,), np.float32))
@@ -100,7 +98,7 @@ def test_f64_leak_rejected_under_x64_trace():
                             ProgramSpec("leak", allow_64=False))
     assert "dtype-widening" in codes(vs)
     # declared 64-bit INPUTS are not leaks (catalog long annotations)
-    with enable_x64():
+    with jax.enable_x64():
         ok = jax.make_jaxpr(lambda x: x * 2)(
             jax.ShapeDtypeStruct((4,), np.int64))
     assert audit_closed_jaxpr(ok, ProgramSpec("ok", allow_64=False)) == []

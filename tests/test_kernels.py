@@ -30,6 +30,20 @@ def test_bitset_and_popcount_sweep(rng, n_blocks, words, pairs):
     np.testing.assert_array_equal(got, want)
 
 
+def test_bitset_and_popcount_chunked_launches(rng, monkeypatch):
+    """Pairs beyond one launch's budget split into several launches
+    (the last one ragged) whose outputs concatenate in order."""
+    from repro.kernels.bitset_intersect import ops
+    monkeypatch.setattr(ops, "_MAX_PAIRS", 1024)
+    blocks = rng.integers(0, 2**32, size=(40, 8), dtype=np.uint32)
+    pa = rng.integers(0, 40, 2500)
+    pb = rng.integers(0, 40, 2500)
+    got = np.asarray(bitset_and_popcount(blocks, pa, pb, interpret=True))
+    want = np.asarray(bitset_and_popcount_ref(jnp.asarray(blocks)[pa],
+                                              jnp.asarray(blocks)[pb]))
+    np.testing.assert_array_equal(got, want)
+
+
 def test_bitset_and_popcount_empty():
     out = bitset_and_popcount(np.zeros((4, 8), np.uint32),
                               np.zeros(0, np.int64), np.zeros(0, np.int64),
