@@ -23,10 +23,8 @@ mentions "backend").
 
 Device rows additionally carry the whole-bag-fusion A/B
 (``bag_fusion.speedup_vs_unfused`` with exact parity, one launch per
-bag vs one per attribute step), the per-query jit-launch budget
-(``pipeline.launches == extend.closing_syncs`` — gated EXACTLY below),
-and the engine-lifetime compile-vs-steady dispatch-wall split
-(``pipeline_wall_split`` — timing, so outside the exact-gated dict).
+bag vs one per attribute step) and the per-query jit-launch budget
+(``pipeline.launches == extend.closing_syncs`` — gated EXACTLY below).
 
 Bench-regression gate (CI): ``--check-baseline benchmarks/baseline.json``
 compares the suite against the committed baseline — wall times within a
@@ -138,14 +136,15 @@ def run_backend_suite(smoke: bool) -> list:
                 # the join work (paper protocol), not cross-query reuse —
                 # within-query cross-rule hits still occur and are counted
                 eng.bag_cache = BagResultCache()
-                before = dict(eng.backend.stats)
+                before = eng.backend.dispatch_summary()
                 t0 = time.perf_counter()
                 res = eng.query(q)
                 walls.append(time.perf_counter() - t0)
                 # last rep's delta: per-execution counts, comparable
                 # between --smoke (1 rep) and full (3 reps) artifacts
+                # (dispatch_summary leaves the spans' wall times out)
                 dispatch = {k: v - before.get(k, 0)
-                            for k, v in eng.backend.stats.items()
+                            for k, v in eng.backend.dispatch_summary().items()
                             if v != before.get(k, 0)}
             digest = _result_digest(res)
             digests.setdefault(qname, digest)
@@ -281,10 +280,6 @@ def run_backend_suite(smoke: bool) -> list:
                         digest, _result_digest(unf_res),
                         rtol=1e-5, atol=1e-6)),
                 }
-            # Compile-vs-steady wall split (engine-lifetime, seconds):
-            # timing, so it lives OUTSIDE the exact-gated dispatch dict
-            if hasattr(eng.backend, "wall_split"):
-                row["pipeline_wall_split"] = dict(eng.backend.wall_split())
             out.append(row)
     return out
 

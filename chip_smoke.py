@@ -180,9 +180,10 @@ def run_pattern(dev, ref, name: str, q: str, scale: int,
     from repro.core.executor import BagResultCache
 
     before = dict(dev.backend.stats)
-    compile0 = dev.backend.wall_compile_s
     res, first = timed(lambda: dev.query(q))
     delta = counter_delta(before, dev.backend.stats)
+    key = "span.eh.bag.launch.compiles"
+    launch_compiles = dev.backend.stats[key] - before.get(key, 0)
     results = [res]
     steady_s = None
     if steady:
@@ -194,8 +195,7 @@ def run_pattern(dev, ref, name: str, q: str, scale: int,
     exp = int(np.asarray(want.scalar()))
     log(f"[pattern] {name} scale={scale} result={got[0]} reference={exp} "
         f"first_s={first:.6f} steady_s={steady_s} "
-        f"dispatch_compile_s="
-        f"{dev.backend.wall_compile_s - compile0:.6f} "
+        f"bag_launch_compiles={launch_compiles} "
         f"reference_s={ref_s:.6f} "
         f"edge_trie_device_bytes="
         f"{trie_device_bytes(dev.catalog.get('Edge'))} "

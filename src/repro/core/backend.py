@@ -32,14 +32,16 @@ host-sync discipline of the extension loop (``extend.calls`` vs
 / ``analysis.candidates_verified`` count validator runs and
 ``analysis.sanitize_checks`` counts passed ``REPRO_SANITIZE`` dispatch
 assertions, so the benchmark artifact's dispatch gate also proves
-verification stayed on.
+verification stayed on.  ``upload.bytes`` counts the bytes put on the
+device.  The engine's spans (``repro.trace``) add their ``span.*`` wall
+times to ``stats`` as well; ``dispatch_summary()`` leaves them out, so
+it holds counts alone.
 """
 from __future__ import annotations
 
 import collections
 import dataclasses
 import os
-import time
 from functools import partial
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -48,6 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from repro import trace
 from repro.core import intersect as I
 from repro.core.layouts import engine_store_for
 from repro.core.semiring import Semiring
@@ -156,12 +159,14 @@ class ExecBackend:
                    threshold: Optional[float] = None):
         """Binary terminal-fold fast path. ``threshold`` is the plan IR's
         statistics-driven Algorithm-3 density threshold (None lets the
-        layout store profile the trie itself)."""
-        store = self._pair_store(trie, threshold)
-        if store is None:
-            return None
-        self.stats["fold.pair_count_calls"] += 1
-        return store.intersect_count(u, v)
+        layout store profile the trie itself).  Returns host arrays, so
+        the device work it launches ends inside its ``eh.pairs`` span."""
+        with trace.span("eh.pairs", self.stats):
+            store = self._pair_store(trie, threshold)
+            if store is None:
+                return None
+            self.stats["fold.pair_count_calls"] += 1
+            return store.intersect_count(u, v)
 
     def pair_materialize(self, trie, u: np.ndarray, v: np.ndarray,
                          threshold: Optional[float] = None):
@@ -169,14 +174,17 @@ class ExecBackend:
         ``Extend.routing == "pair_store"``): cohort-routed
         ``N(u_i) ∩ N(v_i)`` with positions for trie descent, or ``None``
         when the layout store is bypassed."""
-        store = self._pair_store(trie, threshold)
-        if store is None:
-            return None
-        self.stats["extend.pair_materialize_calls"] += 1
-        return store.intersect_materialize(u, v)
+        with trace.span("eh.pairs", self.stats):
+            store = self._pair_store(trie, threshold)
+            if store is None:
+                return None
+            self.stats["extend.pair_materialize_calls"] += 1
+            return store.intersect_materialize(u, v)
 
     def dispatch_summary(self) -> Dict[str, int]:
-        return dict(self.stats)
+        """``stats`` without the spans' wall times: counts only."""
+        return {k: v for k, v in self.stats.items()
+                if not k.startswith("span.")}
 
     def trace_count(self) -> int:
         """Number of distinct traced program shapes this backend has
@@ -256,12 +264,8 @@ class DeviceBackend(ExecBackend):
         # levels in VMEM), so it runs only in interpret mode.
         fm = os.environ.get("REPRO_FRONTIER_FILL", "jnp").strip().lower()
         self.fill_mode = "pallas" if fm == "pallas" else "jnp"
-        # compile-vs-steady wall split: trace keys seen once are charged
-        # to compile wall, repeats to steady wall (informational only —
-        # kept OUT of ``stats`` so the exact dispatch gates stay exact).
+        # trace keys of every program shape dispatched (``trace_count``)
         self._traced: set = set()
-        self.wall_compile_s = 0.0
-        self.wall_steady_s = 0.0
         # engine-lifetime pipeline-cap feedback: bag shape -> the
         # counting pass's measured per-variable totals from an
         # overflow-retried execution, so repeated queries size their
@@ -284,13 +288,19 @@ class DeviceBackend(ExecBackend):
     # ------------------------------------------------------------- uploads
     def _dev_values(self, atom) -> jnp.ndarray:
         lv = atom.trie.levels[atom.depth]
-        return lv.device_values(jnp.asarray, on_upload=self._count_upload)
+        return lv.device_values(self._put, on_upload=self._count_upload)
 
     def _count_upload(self):
         self.stats["upload.levels"] += 1
 
+    def _put(self, arr) -> jnp.ndarray:
+        """Upload one host array, counting its bytes on the device."""
+        arr = np.asarray(arr)
+        self.stats["upload.bytes"] += trace.device_nbytes(arr)
+        return jnp.asarray(arr)
+
     def _up_idx(self, arr) -> jnp.ndarray:
-        return jnp.asarray(np.asarray(arr, dtype=_IDX_NP))
+        return self._put(np.asarray(arr, dtype=_IDX_NP))
 
     def _dev_sideways(self, bs):
         """Device copies of a blocked bitset's DIRECTORY (slot router,
@@ -300,31 +310,12 @@ class DeviceBackend(ExecBackend):
         cached = getattr(bs, "_dev_sideways_cache", None)
         if cached is not None and cached[0] is bs.block_ids:
             return cached[1]
-        dev = (jnp.asarray(np.asarray(bs.slot_of, np.int32)),
+        dev = (self._put(np.asarray(bs.slot_of, np.int32)),
                self._up_idx(bs.offsets),
-               jnp.asarray(np.asarray(bs.block_ids, np.int32)))
+               self._put(np.asarray(bs.block_ids, np.int32)))
         bs._dev_sideways_cache = (bs.block_ids, dev)
         self.stats["upload.bitset_dirs"] += 1
         return dev
-
-    def _timed(self, key, fn, *args, **kw):
-        """Dispatch ``fn`` and charge its wall time to the compile or
-        steady bucket by whether this trace ``key`` was seen before.
-        Measures dispatch/trace wall only (no blocking sync — that
-        would be a transfer, and the whole point is not to have one)."""
-        t0 = time.perf_counter()
-        out = fn(*args, **kw)
-        dt = time.perf_counter() - t0
-        if key in self._traced:
-            self.wall_steady_s += dt
-        else:
-            self._traced.add(key)
-            self.wall_compile_s += dt
-        return out
-
-    def wall_split(self) -> Dict[str, float]:
-        return {"pipeline.wall_compile_s": round(self.wall_compile_s, 6),
-                "pipeline.wall_steady_s": round(self.wall_steady_s, 6)}
 
     def trace_count(self) -> int:
         return len(self._traced)
@@ -340,7 +331,7 @@ class DeviceBackend(ExecBackend):
                 bits_t.append(None)
             else:
                 l0, bs = sw
-                l0v = l0.device_values(jnp.asarray,
+                l0v = l0.device_values(self._put,
                                        on_upload=self._count_upload)
                 slot_d, boffs_d, bids_d = self._dev_sideways(bs)
                 sw_t.append((l0v, slot_d, boffs_d, bids_d))
@@ -364,6 +355,7 @@ class DeviceBackend(ExecBackend):
         values_t = tuple(self._dev_values(a) for a, *_ in infos[1:])
         lo_t = tuple(info[2][row_id] for info in infos[1:])
         hi_t = tuple(info[3][row_id] for info in infos[1:])
+        self.stats["upload.bytes"] += trace.device_nbytes(p0, *lo_t, *hi_t)
         pos_t, found = _fused_probe(values_t, lo_t, hi_t, vals_dev)
         # the ONLY host round-trip of this extension: every probe atom's
         # positions + the combined membership mask come back together.
@@ -398,7 +390,7 @@ class DeviceBackend(ExecBackend):
     def pipeline_begin(self, cursors0: Dict[int, np.ndarray],
                        ann0: Optional[np.ndarray]) -> "DeviceFrontier":
         cursors = {k: self._up_idx(c) for k, c in cursors0.items()}
-        ann = jnp.asarray(ann0) if ann0 is not None else None
+        ann = self._put(ann0) if ann0 is not None else None
         return DeviceFrontier(
             cap=1, count=jnp.asarray(1, _IDX),
             overflow=jnp.asarray(False),
@@ -425,7 +417,7 @@ class DeviceBackend(ExecBackend):
             self.stats["pipeline.sideways_extends"] += 1
 
         def triple(key, lv, d0, _sw):
-            vals = lv.device_values(jnp.asarray,
+            vals = lv.device_values(self._put,
                                     on_upload=self._count_upload)
             if d0:
                 return (vals, None, None)
@@ -444,16 +436,16 @@ class DeviceBackend(ExecBackend):
                  + tuple(state.cursors[k] for k in cur_keys)
                  + ((state.ann,) if state.ann is not None else ()))
 
-        (count, overflow, chunks, total, vals_c, p0_c, pos_c,
-         carry_c) = self._timed(
-            ("step", state.cap, int(cap_out), int(morsel), probe_d0,
-             sideways_bits, len(carry)),
-            _pipeline_step,
-            state.count, state.overflow, seed, probes, sideways, carry,
-            cap_in=state.cap, cap_out=int(cap_out), morsel=int(morsel),
-            seed_d0=bool(cons[0][2]), probe_d0=probe_d0,
-            sideways_bits=sideways_bits, fill_mode=self.fill_mode,
-            fill_interpret=self._interpret)
+        self._traced.add(("step", state.cap, int(cap_out), int(morsel),
+                          probe_d0, sideways_bits, len(carry)))
+        with trace.span("eh.bag.launch", self.stats):
+            (count, overflow, chunks, total, vals_c, p0_c, pos_c,
+             carry_c) = _pipeline_step(
+                state.count, state.overflow, seed, probes, sideways, carry,
+                cap_in=state.cap, cap_out=int(cap_out), morsel=int(morsel),
+                seed_d0=bool(cons[0][2]), probe_d0=probe_d0,
+                sideways_bits=sideways_bits, fill_mode=self.fill_mode,
+                fill_interpret=self._interpret)
 
         it = iter(carry_c)
         cols = {k: next(it) for k in col_keys}
@@ -489,7 +481,7 @@ class DeviceBackend(ExecBackend):
         self.stats["pipeline.launches"] += 1
 
         def triple(key, lv, d0, _ann):
-            vals = lv.device_values(jnp.asarray,
+            vals = lv.device_values(self._put,
                                     on_upload=self._count_upload)
             if d0:
                 return (vals, None, None)
@@ -500,7 +492,7 @@ class DeviceBackend(ExecBackend):
         def leaf(c):
             if c[3] is None:
                 return None
-            return c[3].device_annotation(jnp.asarray,
+            return c[3].device_annotation(self._put,
                                           on_upload=self._count_upload)
 
         seed = triple(*cons[0])
@@ -512,13 +504,13 @@ class DeviceBackend(ExecBackend):
         carry = (tuple(state.cols[k] for k in col_keys)
                  + tuple(state.cursors[k] for k in cur_keys))
 
-        count, chunks, ann_c, carry_c = self._timed(
-            ("fold", state.cap, int(morsel), probe_d0, sr.name,
-             len(carry)),
-            _pipeline_fold,
-            state.count, seed, probes, state.ann, leaf_anns, carry,
-            cap_in=state.cap, morsel=int(morsel),
-            seed_d0=bool(cons[0][2]), probe_d0=probe_d0, sr=sr)
+        self._traced.add(("fold", state.cap, int(morsel), probe_d0,
+                          sr.name, len(carry)))
+        with trace.span("eh.bag.launch", self.stats):
+            count, chunks, ann_c, carry_c = _pipeline_fold(
+                state.count, seed, probes, state.ann, leaf_anns, carry,
+                cap_in=state.cap, morsel=int(morsel),
+                seed_d0=bool(cons[0][2]), probe_d0=probe_d0, sr=sr)
 
         it = iter(carry_c)
         cols = {k: next(it) for k in col_keys}
@@ -534,7 +526,7 @@ class DeviceBackend(ExecBackend):
         """Multiply an exhausted atom's annotation into the device-
         resident frontier annotation (eager jnp ops: async dispatch, no
         sync).  Mirrors the host loop's leaf-annotation multiply."""
-        ann_dev = trie.device_annotation(jnp.asarray,
+        ann_dev = trie.device_annotation(self._put,
                                          on_upload=self._count_upload)
         cur = state.cursors[cursor_key]
         n = ann_dev.shape[0]
@@ -559,23 +551,25 @@ class DeviceBackend(ExecBackend):
         and XLA sees step k's compaction and step k+1's counting pass in
         one module, fusing across the attribute boundary.  One launch
         per bag; the closing ``pipeline_land`` stays the only transfer.
+        Lowering and dispatch run inside the span ``eh.bag.launch``.
         """
-        self.stats["pipeline.launches"] += 1
-        prog_t, arrays, canon, cap = self._lower_bag(steps, cursors0)
-        cur_canon = {canon[k]: self._up_idx(c)
-                     for k, c in cursors0.items()}
-        ann = jnp.asarray(ann0) if ann0 is not None else None
-        if self.audit_log is not None:
-            self.audit_log.append(
-                ("bag", "bag", prog_t, audit_avals(tuple(arrays)),
-                 audit_avals(cur_canon), audit_avals(ann),
-                 self.fill_mode, self._interpret))
-        (count, overflow, morsels, lcounts, needs, cols, cursors,
-         ann_o) = self._timed(
-            ("bag", prog_t, self.fill_mode),
-            _bag_program, tuple(arrays), cur_canon, ann,
-            prog=prog_t, fill_mode=self.fill_mode,
-            fill_interpret=self._interpret)
+        with trace.span("eh.bag.launch", self.stats):
+            self.stats["pipeline.launches"] += 1
+            prog_t, arrays, canon, cap = self._lower_bag(steps, cursors0)
+            cur_canon = {canon[k]: self._up_idx(c)
+                         for k, c in cursors0.items()}
+            ann = self._put(ann0) if ann0 is not None else None
+            if self.audit_log is not None:
+                self.audit_log.append(
+                    ("bag", "bag", prog_t, audit_avals(tuple(arrays)),
+                     audit_avals(cur_canon), audit_avals(ann),
+                     self.fill_mode, self._interpret))
+            self._traced.add(("bag", prog_t, self.fill_mode))
+            (count, overflow, morsels, lcounts, needs, cols, cursors,
+             ann_o) = _bag_program(
+                tuple(arrays), cur_canon, ann,
+                prog=prog_t, fill_mode=self.fill_mode,
+                fill_interpret=self._interpret)
         id_of = {v: k for k, v in canon.items()}
         lvars = [s[1] for s in prog_t if s[0] in ("extend", "fold")]
         evars = [s[1] for s in prog_t if s[0] == "extend"]
@@ -617,7 +611,7 @@ class DeviceBackend(ExecBackend):
             return i
 
         def upload(lv, d0):
-            vals_i = aref(lv.device_values(jnp.asarray,
+            vals_i = aref(lv.device_values(self._put,
                                            on_upload=self._count_upload))
             offs_i = -1 if d0 else aref(lv.device_offsets(
                 self._up_idx, on_upload=self._count_upload))
@@ -642,7 +636,7 @@ class DeviceBackend(ExecBackend):
                     if sw is not None and i > 0:
                         l0, bs = sw
                         l0v = l0.device_values(
-                            jnp.asarray, on_upload=self._count_upload)
+                            self._put, on_upload=self._count_upload)
                         slot_d, boffs_d, bids_d = self._dev_sideways(bs)
                         swt = (aref(l0v), aref(slot_d), aref(boffs_d),
                                aref(bids_d), int(bs.block_bits))
@@ -660,14 +654,14 @@ class DeviceBackend(ExecBackend):
                     ann_i = -1
                     if ann_trie is not None:
                         ann_i = aref(ann_trie.device_annotation(
-                            jnp.asarray, on_upload=self._count_upload))
+                            self._put, on_upload=self._count_upload))
                     cdescs.append((ckey(key), vals_i, offs_i, ann_i))
                 prog.append(("fold", var, int(morsel), sr,
                              tuple(cdescs)))
             elif kind == "annmul":
                 _, key, trie, sr = step
                 ann_i = aref(trie.device_annotation(
-                    jnp.asarray, on_upload=self._count_upload))
+                    self._put, on_upload=self._count_upload))
                 prog.append(("annmul", ckey(key), ann_i, sr))
             else:
                 raise ValueError(f"unknown bag step {kind!r}")
@@ -696,23 +690,24 @@ class DeviceBackend(ExecBackend):
         EXACT.
         """
         b = next(iter(cursors0.values())).shape[0]
-        self.stats["pipeline.launches"] += 1
-        self.stats["pipeline.batched_launches"] += 1
-        self.stats["pipeline.batched_queries"] += int(b)
-        prog_t, arrays, canon, cap = self._lower_bag(steps, cursors0)
-        cur_canon = {canon[k]: self._up_idx(c)
-                     for k, c in cursors0.items()}
-        ann = jnp.asarray(ann0) if ann0 is not None else None
-        if self.audit_log is not None:
-            self.audit_log.append(
-                ("bag_batch", "bag_batch", prog_t,
-                 audit_avals(tuple(arrays)), audit_avals(cur_canon),
-                 audit_avals(ann), int(b), self._interpret))
-        (count, overflow, morsels, lcounts, needs, cols, cursors,
-         ann_o) = self._timed(
-            ("bag_batch", prog_t, int(b)),
-            _bag_program_batch, tuple(arrays), cur_canon, ann,
-            prog=prog_t, fill_interpret=self._interpret)
+        with trace.span("eh.bag.launch", self.stats):
+            self.stats["pipeline.launches"] += 1
+            self.stats["pipeline.batched_launches"] += 1
+            self.stats["pipeline.batched_queries"] += int(b)
+            prog_t, arrays, canon, cap = self._lower_bag(steps, cursors0)
+            cur_canon = {canon[k]: self._up_idx(c)
+                         for k, c in cursors0.items()}
+            ann = self._put(ann0) if ann0 is not None else None
+            if self.audit_log is not None:
+                self.audit_log.append(
+                    ("bag_batch", "bag_batch", prog_t,
+                     audit_avals(tuple(arrays)), audit_avals(cur_canon),
+                     audit_avals(ann), int(b), self._interpret))
+            self._traced.add(("bag_batch", prog_t, int(b)))
+            (count, overflow, morsels, lcounts, needs, cols, cursors,
+             ann_o) = _bag_program_batch(
+                tuple(arrays), cur_canon, ann,
+                prog=prog_t, fill_interpret=self._interpret)
         id_of = {v: k for k, v in canon.items()}
         lvars = [s[1] for s in prog_t if s[0] in ("extend", "fold")]
         evars = [s[1] for s in prog_t if s[0] == "extend"]
@@ -726,7 +721,9 @@ class DeviceBackend(ExecBackend):
     def pipeline_land_batched(self, state: "BatchedFrontier"):
         """THE closing sync of a batched bag run: every query's compacted
         frontier, per-level counts and overflow flag in ONE transfer
-        (``extend.closing_syncs`` += 1 for the whole batch)."""
+        (``extend.closing_syncs`` += 1 for the whole batch).  The slots
+        landed (B x cap) and the rows live in them add to
+        ``pipeline.batched_slots`` / ``pipeline.batched_rows``."""
         scal = jnp.stack(
             [state.count.astype(_IDX), state.overflow.astype(_IDX),
              state.morsels.astype(_IDX)]
@@ -741,6 +738,8 @@ class DeviceBackend(ExecBackend):
         self.stats["extend.closing_syncs"] += 1
         nl = len(state.level_counts)
         counts = np.asarray(scal_h[0], dtype=np.int64)
+        self.stats["pipeline.batched_slots"] += state.batch * state.cap
+        self.stats["pipeline.batched_rows"] += int(counts.sum())
         overflows = np.asarray(scal_h[1]).astype(bool)
         self.stats["pipeline.morsels"] += int(np.asarray(scal_h[2]).sum())
         # worst case over the batch per variable: the retry loop sizes

@@ -35,6 +35,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import trace
 from repro.core import codegen as codegen_mod
 from repro.core import plan_ir
 from repro.core import plan_search as plan_search_mod
@@ -304,21 +305,24 @@ class Engine:
 
     # ---------------------------------------------------------------- query
     def query(self, text: str) -> QueryResult:
-        """Run a datalog program; returns the result of the LAST head."""
-        prog = parse(text)
-        self._program_metadata = []
-        result: Optional[QueryResult] = None
-        for i, rule in enumerate(prog.rules):
-            is_star_base = (rule.recursion is None and
-                            any(r.recursion is not None and
-                                r.head.rel == rule.head.rel
-                                for r in prog.rules[i + 1:]))
-            if rule.recursion is not None:
-                result = self._eval_recursive(rule)
-            else:
-                result = self._eval_rule(rule, materialize=True or is_star_base)
-        assert result is not None, "empty program"
-        return result
+        """Run a datalog program; returns the result of the LAST head.
+        The whole call is the root span ``eh.query``."""
+        with trace.span("eh.query", self.backend.stats):
+            prog = parse(text)
+            self._program_metadata = []
+            result: Optional[QueryResult] = None
+            for i, rule in enumerate(prog.rules):
+                is_star_base = (rule.recursion is None and
+                                any(r.recursion is not None and
+                                    r.head.rel == rule.head.rel
+                                    for r in prog.rules[i + 1:]))
+                if rule.recursion is not None:
+                    result = self._eval_recursive(rule)
+                else:
+                    result = self._eval_rule(rule,
+                                             materialize=True or is_star_base)
+            assert result is not None, "empty program"
+            return result
 
     def prepare(self, text: str) -> PreparedQuery:
         """Compile ONE non-recursive rule with its selection constants
@@ -377,18 +381,19 @@ class Engine:
 
     # ------------------------------------------------------------ internals
     def _compile(self, rule: Rule) -> QueryPlan:
-        key = (repr(rule), self.use_ghd)
-        plan = self._plan_cache.get(key)
-        if plan is None:
-            self.backend.stats["compile.logical_compiles"] += 1
-            plan = compile_rule(rule, use_ghd=self.use_ghd)
-            if plan.semiring is not None and plan.needs_top_down:
-                plan = compile_rule(rule, use_ghd=False)
-            self._plan_cache[key] = plan
-        else:
-            self.backend.stats["compile.plan_cache_hits"] += 1
-        self.last_plan = plan
-        return plan
+        with trace.span("eh.plan", self.backend.stats):
+            key = (repr(rule), self.use_ghd)
+            plan = self._plan_cache.get(key)
+            if plan is None:
+                self.backend.stats["compile.logical_compiles"] += 1
+                plan = compile_rule(rule, use_ghd=self.use_ghd)
+                if plan.semiring is not None and plan.needs_top_down:
+                    plan = compile_rule(rule, use_ghd=False)
+                self._plan_cache[key] = plan
+            else:
+                self.backend.stats["compile.plan_cache_hits"] += 1
+            self.last_plan = plan
+            return plan
 
     def _physical(self, plan: QueryPlan):
         """Physical plan (+ emitted source) for ``plan`` against the
@@ -403,53 +408,54 @@ class Engine:
         With the cost-based plan search on, the first execution of a rule
         runs the full candidate search (``core.plan_search``); the chosen
         logical plan is pinned in ``_search_cache`` so later rounds only
-        re-annotate it."""
-        rels = tuple(sorted({a.rel for a in plan.rule.body}))
-        key = (repr(plan.rule), self.use_ghd, self.use_codegen,
-               self.plan_search, self.catalog.version_key(rels))
-        hit = self._physical_cache.get(key)
-        if hit is None:
-            self.backend.stats["compile.physical_builds"] += 1
-            search_md = None
-            if self.plan_search:
-                dkey = (repr(plan.rule), self.use_ghd)
-                decided = self._search_cache.get(dkey)
-                if decided is None:
-                    self.backend.stats["compile.plan_searches"] += 1
-                    sr = plan_search_mod.search(
-                        plan, self.stats_catalog, self.catalog,
-                        bag_cache=self.bag_cache, use_ghd=self.use_ghd,
-                        verify=self.verify_plans,
-                        counter=self.backend.stats)
-                    decided = (sr.chosen, sr.metadata())
-                    if len(self._search_cache) >= 256:
-                        self._search_cache.pop(
-                            next(iter(self._search_cache)))
-                    self._search_cache[dkey] = decided
-                    pplan = sr.physical
+        re-annotate it.  Runs inside the span ``eh.plan``."""
+        with trace.span("eh.plan", self.backend.stats):
+            rels = tuple(sorted({a.rel for a in plan.rule.body}))
+            key = (repr(plan.rule), self.use_ghd, self.use_codegen,
+                   self.plan_search, self.catalog.version_key(rels))
+            hit = self._physical_cache.get(key)
+            if hit is None:
+                self.backend.stats["compile.physical_builds"] += 1
+                search_md = None
+                if self.plan_search:
+                    dkey = (repr(plan.rule), self.use_ghd)
+                    decided = self._search_cache.get(dkey)
+                    if decided is None:
+                        self.backend.stats["compile.plan_searches"] += 1
+                        sr = plan_search_mod.search(
+                            plan, self.stats_catalog, self.catalog,
+                            bag_cache=self.bag_cache, use_ghd=self.use_ghd,
+                            verify=self.verify_plans,
+                            counter=self.backend.stats)
+                        decided = (sr.chosen, sr.metadata())
+                        if len(self._search_cache) >= 256:
+                            self._search_cache.pop(
+                                next(iter(self._search_cache)))
+                        self._search_cache[dkey] = decided
+                        pplan = sr.physical
+                    else:
+                        pplan = plan_ir.build_physical_plan(
+                            decided[0], self.stats_catalog, self.catalog)
+                    search_md = decided[1]
                 else:
                     pplan = plan_ir.build_physical_plan(
-                        decided[0], self.stats_catalog, self.catalog)
-                search_md = decided[1]
+                        plan, self.stats_catalog, self.catalog)
+                if self.verify_plans:
+                    # static proof obligations on the plan execution is about
+                    # to consume — the search path verified candidates too;
+                    # this re-checks the final (re-annotated) lowering
+                    from repro.analysis import assert_valid
+                    assert_valid(pplan, self.catalog, self.stats_catalog)
+                    self.backend.stats["analysis.plans_verified"] += 1
+                fn = src = None
+                if self.use_codegen:
+                    fn, src = codegen_mod.emit(pplan)
+                if len(self._physical_cache) >= 256:
+                    self._physical_cache.pop(next(iter(self._physical_cache)))
+                hit = self._physical_cache[key] = (pplan, fn, src, search_md)
             else:
-                pplan = plan_ir.build_physical_plan(plan, self.stats_catalog,
-                                                    self.catalog)
-            if self.verify_plans:
-                # static proof obligations on the plan execution is about
-                # to consume — the search path verified candidates too;
-                # this re-checks the final (re-annotated) lowering
-                from repro.analysis import assert_valid
-                assert_valid(pplan, self.catalog, self.stats_catalog)
-                self.backend.stats["analysis.plans_verified"] += 1
-            fn = src = None
-            if self.use_codegen:
-                fn, src = codegen_mod.emit(pplan)
-            if len(self._physical_cache) >= 256:
-                self._physical_cache.pop(next(iter(self._physical_cache)))
-            hit = self._physical_cache[key] = (pplan, fn, src, search_md)
-        else:
-            self.backend.stats["compile.physical_cache_hits"] += 1
-        return hit
+                self.backend.stats["compile.physical_cache_hits"] += 1
+            return hit
 
     def _execute(self, plan: QueryPlan, encode=None) -> GJResult:
         pplan, fn, src, search_md = self._physical(plan)
@@ -519,21 +525,23 @@ class Engine:
             return None
         lplan = pplan.logical
         joins: List[GenericJoin] = []
-        for binding in bindings:
-            enc = self._binding_encode(binding)
-            gj_atoms = []
-            selections: Dict[int, Dict[int, int]] = {}
-            for acc in bops.scan.accesses:
-                sel = acc.selection_map(enc)
-                if sel:
-                    selections[len(gj_atoms)] = sel
-                gj_atoms.append((self.catalog.reordered(acc.rel, acc.perm),
-                                 acc.vars))
-            joins.append(GenericJoin(
-                gj_atoms, bops.scan.var_order,
-                bops.materialize.output_vars, semiring=lplan.semiring,
-                selections=selections, backend=self.backend,
-                hints=bops.hints()))
+        with trace.span("eh.bind", self.backend.stats):
+            for binding in bindings:
+                enc = self._binding_encode(binding)
+                gj_atoms = []
+                selections: Dict[int, Dict[int, int]] = {}
+                for acc in bops.scan.accesses:
+                    sel = acc.selection_map(enc)
+                    if sel:
+                        selections[len(gj_atoms)] = sel
+                    gj_atoms.append((self.catalog.reordered(acc.rel,
+                                                            acc.perm),
+                                     acc.vars))
+                joins.append(GenericJoin(
+                    gj_atoms, bops.scan.var_order,
+                    bops.materialize.output_vars, semiring=lplan.semiring,
+                    selections=selections, backend=self.backend,
+                    hints=bops.hints()))
         results = run_batched(joins)
         if results is None:
             return None

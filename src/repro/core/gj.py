@@ -37,6 +37,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import trace
 from repro.core import backend as backend_mod
 from repro.core import statistics as stats_mod
 from repro.core.semiring import COUNT, Semiring
@@ -616,15 +617,18 @@ class GenericJoin:
         """Project a landed frontier to the output variables (group-by
         with the semiring fold, or dedup, where non-retained columns
         survived).  Touches no atom state, so ``run_batched`` reuses the
-        template join's instance for every batch element."""
-        out_set = set(self.output_vars)
-        cols = {k: frontier[k] for k in self.output_vars if k in frontier}
-        extra = [k for k in frontier if k not in out_set]
-        if not extra and len(cols) == len(self.output_vars):
-            return GJResult(self.output_vars, cols,
-                            np.asarray(ann) if ann is not None else None)
-        # group-by output vars, folding ann (or dedup)
-        return self._project(cols, ann, F)
+        template join's instance for every batch element.  Runs inside
+        the span ``eh.finalize``."""
+        with trace.span("eh.finalize", self.backend.stats):
+            out_set = set(self.output_vars)
+            cols = {k: frontier[k] for k in self.output_vars
+                    if k in frontier}
+            extra = [k for k in frontier if k not in out_set]
+            if not extra and len(cols) == len(self.output_vars):
+                return GJResult(self.output_vars, cols,
+                                np.asarray(ann) if ann is not None else None)
+            # group-by output vars, folding ann (or dedup)
+            return self._project(cols, ann, F)
 
     # ------------------------------------------------------------ internals
     def _extend(self, cons: List[BoundAtom], F: int):

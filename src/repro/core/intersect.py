@@ -18,6 +18,11 @@ implemented, mirroring the paper:
 
 Pure-numpy twins (`*_np`) serve as oracles for tests and for the Pallas
 kernels' ``ref.py`` modules.
+
+The host arithmetic of the pair paths (expansion before a search, index
+arithmetic after it) runs inside ``eh.pairs.expand`` spans
+(``repro.trace``); each search counts its lanes (``pairs.searched``),
+its matches (``pairs.found``) and its operands (``upload.bytes``).
 """
 from __future__ import annotations
 
@@ -28,6 +33,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro import trace
 
 # Ratio at which Algorithm 2 switches to the min-property search algorithm.
 GALLOP_RATIO = 32
@@ -85,6 +92,18 @@ def segment_searchsorted(values, lo, hi, queries, iters: int = 34):
     return lo_f, found
 
 
+def _search(values, lo, hi, queries):
+    """``segment_searchsorted`` for the host pair paths: counts the
+    operands it uploads and its lanes, and returns ``found`` on the host
+    (``pos`` stays on the device)."""
+    trace.upload(values, lo, hi, queries)
+    pos, found = segment_searchsorted(values, lo, hi, queries)
+    found = np.asarray(found)
+    trace.add("pairs.searched", len(found))
+    trace.add("pairs.found", np.count_nonzero(found))
+    return pos, found
+
+
 def segment_searchsorted_np(values, lo, hi, queries):
     """Numpy oracle for segment_searchsorted (loop over queries)."""
     pos = np.empty(len(queries), dtype=np.int64)
@@ -134,13 +153,15 @@ def intersect_count_uint(offsets: np.ndarray, neighbors: np.ndarray,
     out = np.zeros(len(u), dtype=np.int64)
     if len(u) == 0:
         return out
+    trace.upload(neighbors)
     values_dev = jnp.asarray(neighbors)
-    pair_id, _, q, lo, hi = _expand_smaller(offsets, neighbors, u, v)
+    with trace.span("eh.pairs.expand"):
+        pair_id, _, q, lo, hi = _expand_smaller(offsets, neighbors, u, v)
     for s in range(0, len(pair_id), chunk):
         e = min(s + chunk, len(pair_id))
-        _, found = segment_searchsorted(values_dev, lo[s:e], hi[s:e], q[s:e])
-        found = np.asarray(found)
-        np.add.at(out, pair_id[s:e], found.astype(np.int64))
+        _, found = _search(values_dev, lo[s:e], hi[s:e], q[s:e])
+        with trace.span("eh.pairs.expand"):
+            np.add.at(out, pair_id[s:e], found.astype(np.int64))
     return out
 
 
@@ -154,20 +175,22 @@ def intersect_pairs_uint(offsets: np.ndarray, neighbors: np.ndarray,
     if len(u) == 0:
         z = np.zeros(0, np.int64)
         return z, np.zeros(0, np.int32), z, z
-    deg = np.diff(offsets)
-    swap = deg[u] > deg[v]
-    pair_id, elem_idx, q, lo, hi = _expand_smaller(offsets, neighbors, u, v)
-    pos, found = segment_searchsorted(jnp.asarray(neighbors), lo, hi, q)
-    found = np.asarray(found)
+    with trace.span("eh.pairs.expand"):
+        deg = np.diff(offsets)
+        swap = deg[u] > deg[v]
+        pair_id, elem_idx, q, lo, hi = _expand_smaller(offsets, neighbors,
+                                                       u, v)
+    pos, found = _search(neighbors, lo, hi, q)
     pos = np.asarray(pos)
-    keep = found
-    pair_id = pair_id[keep]
-    vals = q[keep]
-    small_pos = elem_idx[keep]
-    large_pos = pos[keep]
-    sw = swap[pair_id]
-    pos_u = np.where(sw, large_pos, small_pos)
-    pos_v = np.where(sw, small_pos, large_pos)
+    with trace.span("eh.pairs.expand"):
+        keep = found
+        pair_id = pair_id[keep]
+        vals = q[keep]
+        small_pos = elem_idx[keep]
+        large_pos = pos[keep]
+        sw = swap[pair_id]
+        pos_u = np.where(sw, large_pos, small_pos)
+        pos_v = np.where(sw, small_pos, large_pos)
     return pair_id, vals, pos_u, pos_v
 
 
@@ -264,13 +287,15 @@ def bitset_intersect_count(bs: BlockedBitset, a_slots: np.ndarray,
     pair_id, _, pos_a, pos_b = intersect_pairs_uint(
         bs.offsets, bs.block_ids, np.asarray(a_slots, np.int64),
         np.asarray(b_slots, np.int64))
-    if word_and_popcount is None:
-        word_and_popcount = _word_and_popcount_jnp
     if len(pair_id) == 0:
         return np.zeros(len(a_slots), dtype=np.int64)
+    if word_and_popcount is None:
+        word_and_popcount = _word_and_popcount_jnp
+        trace.upload(bs.words, pos_a, pos_b)
     per_block = np.asarray(word_and_popcount(bs.words, pos_a, pos_b))
     out = np.zeros(len(a_slots), dtype=np.int64)
-    np.add.at(out, pair_id, per_block.astype(np.int64))
+    with trace.span("eh.pairs.expand"):
+        np.add.at(out, pair_id, per_block.astype(np.int64))
     return out
 
 
@@ -305,31 +330,32 @@ def bitset_intersect_materialize(bs: BlockedBitset, a_slots: np.ndarray,
     z = np.zeros(0, np.int64)
     if len(pair_id) == 0:
         return z, np.zeros(0, np.int32), z, z
-    wa = bs.words[pos_a]                      # [B', wpb] uint32
-    wb = bs.words[pos_b]
-    wand = wa & wb
-    # extract set bits of each AND-ed block: little-endian unpack keeps
-    # (block, bit-position) row-major, so matches come out
-    # block-ascending then value-ascending; uint8 unpack avoids the 32x
-    # uint32 broadcast blow-up on large dense frontiers
-    flat = np.unpackbits(wand.view(np.uint8), axis=1, bitorder="little")
-    blk_row, bitpos = np.nonzero(flat)
-    word_idx = bitpos >> 5
-    bit_idx = bitpos & 31
-    vals = (bs.block_ids[pos_a[blk_row]].astype(np.int64) * bs.block_bits
-            + bitpos)
-    below = (np.uint32(1) << bit_idx.astype(np.uint32)) - np.uint32(1)
+    with trace.span("eh.pairs.expand"):
+        wa = bs.words[pos_a]                      # [B', wpb] uint32
+        wb = bs.words[pos_b]
+        wand = wa & wb
+        # extract set bits of each AND-ed block: little-endian unpack keeps
+        # (block, bit-position) row-major, so matches come out
+        # block-ascending then value-ascending; uint8 unpack avoids the 32x
+        # uint32 broadcast blow-up on large dense frontiers
+        flat = np.unpackbits(wand.view(np.uint8), axis=1, bitorder="little")
+        blk_row, bitpos = np.nonzero(flat)
+        word_idx = bitpos >> 5
+        bit_idx = bitpos & 31
+        vals = (bs.block_ids[pos_a[blk_row]].astype(np.int64) * bs.block_bits
+                + bitpos)
+        below = (np.uint32(1) << bit_idx.astype(np.uint32)) - np.uint32(1)
 
-    def rank(words, pos):
-        per_word = popcount_u32_np(words)             # [B', wpb]
-        cum = np.cumsum(per_word, axis=1) - per_word  # exclusive per word
-        return (bs.index[pos[blk_row]]
-                + cum[blk_row, word_idx]
-                + popcount_u32_np(words[blk_row, word_idx] & below))
+        def rank(words, pos):
+            per_word = popcount_u32_np(words)             # [B', wpb]
+            cum = np.cumsum(per_word, axis=1) - per_word  # exclusive per word
+            return (bs.index[pos[blk_row]]
+                    + cum[blk_row, word_idx]
+                    + popcount_u32_np(words[blk_row, word_idx] & below))
 
-    return (pair_id[blk_row], vals.astype(np.int32),
-            rank(wa, pos_a).astype(np.int64),
-            rank(wb, pos_b).astype(np.int64))
+        return (pair_id[blk_row], vals.astype(np.int32),
+                rank(wa, pos_a).astype(np.int64),
+                rank(wb, pos_b).astype(np.int64))
 
 
 def uint_bitset_intersect_count(offsets, neighbors, u: np.ndarray,
@@ -341,23 +367,27 @@ def uint_bitset_intersect_count(offsets, neighbors, u: np.ndarray,
     constant set by the block size."""
     u = np.asarray(u, dtype=np.int64)
     b_slots = np.asarray(b_slots, dtype=np.int64)
-    deg = np.diff(offsets)
-    cnt = deg[u]
-    pair_id = np.repeat(np.arange(len(u), dtype=np.int64), cnt)
-    starts = offsets[u]
-    base = np.repeat(starts, cnt)
-    local = np.arange(len(pair_id), dtype=np.int64)
-    seg_start = np.repeat(np.concatenate([[0], np.cumsum(cnt)])[:-1], cnt)
-    elems = neighbors[base + (local - seg_start)].astype(np.int64)
+    with trace.span("eh.pairs.expand"):
+        deg = np.diff(offsets)
+        cnt = deg[u]
+        pair_id = np.repeat(np.arange(len(u), dtype=np.int64), cnt)
+        starts = offsets[u]
+        base = np.repeat(starts, cnt)
+        local = np.arange(len(pair_id), dtype=np.int64)
+        seg_start = np.repeat(np.concatenate([[0], np.cumsum(cnt)])[:-1],
+                              cnt)
+        elems = neighbors[base + (local - seg_start)].astype(np.int64)
 
-    blk = (elems // bs.block_bits).astype(np.int32)
-    lo = bs.offsets[b_slots][pair_id]
-    hi = bs.offsets[b_slots + 1][pair_id]
-    pos, found = segment_searchsorted(jnp.asarray(bs.block_ids), lo, hi, blk)
-    pos = np.asarray(pos); found = np.asarray(found)
-    bit = elems % bs.block_bits
-    w = bs.words[np.clip(pos, 0, len(bs.block_ids) - 1), bit // 32]
-    hit = found & (((w >> (bit % 32).astype(np.uint32)) & 1).astype(bool))
-    out = np.zeros(len(u), dtype=np.int64)
-    np.add.at(out, pair_id, hit.astype(np.int64))
+        blk = (elems // bs.block_bits).astype(np.int32)
+        lo = bs.offsets[b_slots][pair_id]
+        hi = bs.offsets[b_slots + 1][pair_id]
+    pos, found = _search(bs.block_ids, lo, hi, blk)
+    pos = np.asarray(pos)
+    with trace.span("eh.pairs.expand"):
+        bit = elems % bs.block_bits
+        w = bs.words[np.clip(pos, 0, len(bs.block_ids) - 1), bit // 32]
+        hit = found & (((w >> (bit % 32).astype(np.uint32)) & 1)
+                       .astype(bool))
+        out = np.zeros(len(u), dtype=np.int64)
+        np.add.at(out, pair_id, hit.astype(np.int64))
     return out

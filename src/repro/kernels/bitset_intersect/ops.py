@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import trace
 from repro.kernels.bitset_intersect.kernel import bitset_and_popcount_kernel
 from repro.kernels.common import LANE, interpret_default, round_up
 
@@ -30,6 +31,7 @@ def bitset_and_popcount(words, pos_a, pos_b, *, interpret=None):
     """
     if interpret is None:
         interpret = interpret_default()
+    trace.upload(words, pos_a, pos_b)
     words = jnp.asarray(words)
     pos_a = jnp.asarray(pos_a)
     pos_b = jnp.asarray(pos_b)

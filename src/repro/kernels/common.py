@@ -16,6 +16,8 @@ from __future__ import annotations
 import jax
 import numpy as np
 
+from repro import trace
+
 # Native tile geometry for fp32/int32 operands.
 SUBLANE = 8
 LANE = 128
@@ -86,5 +88,8 @@ def host_get(tree):
     ``sync_baseline.json``) audits exactly one ``device_get`` for the
     whole device path.  Adding a transfer anywhere else in the budgeted
     modules fails the linter; adding one here fails the baseline count.
+    The span ``eh.land`` around it holds the wait for the device as well
+    as the transfer.
     """
-    return jax.device_get(tree)
+    with trace.span("eh.land"):
+        return jax.device_get(tree)

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import trace
 from repro.kernels.common import host_get, interpret_default, round_up
 from repro.kernels.materialize.kernel import bitset_materialize_kernel
 
@@ -66,6 +67,7 @@ def _device_words(bs) -> jnp.ndarray:
     ``TrieLevel.device_values`` idiom)."""
     cached = bs.__dict__.get("_dev_words")
     if cached is None or cached[0] is not bs.words:
+        trace.upload(bs.words)
         cached = (bs.words, jnp.asarray(bs.words))
         bs._dev_words = cached
     return cached[1]
@@ -89,6 +91,7 @@ def bitset_pair_materialize(bs, a_slots, b_slots, *, interpret=None):
     z = np.zeros(0, np.int64)
     if len(pair_id) == 0:
         return z, np.zeros(0, np.int32), z, z
+    trace.upload(pos_a, pos_b)
     band, ra, rb = _gather_expand_rank(
         _device_words(bs), jnp.asarray(pos_a), jnp.asarray(pos_b),
         _tri(bs.block_bits), block_bits=bs.block_bits,
@@ -99,13 +102,14 @@ def bitset_pair_materialize(bs, a_slots, b_slots, *, interpret=None):
     total, pos_c, ra_c, rb_c = _extract_pairs(band, ra, rb)
     total, pos_h, ra_h, rb_h = host_get((total, pos_c, ra_c, rb_c))
     n = int(total)
-    pos_h = np.asarray(pos_h)[:n].astype(np.int64)
-    blk_row = pos_h // bs.block_bits
-    bitpos = pos_h % bs.block_bits
-    vals = (bs.block_ids[pos_a[blk_row]].astype(np.int64) * bs.block_bits
-            + bitpos)
-    rank_a = bs.index[pos_a[blk_row]] + np.asarray(ra_h)[:n]
-    rank_b = bs.index[pos_b[blk_row]] + np.asarray(rb_h)[:n]
+    with trace.span("eh.pairs.expand"):
+        pos_h = np.asarray(pos_h)[:n].astype(np.int64)
+        blk_row = pos_h // bs.block_bits
+        bitpos = pos_h % bs.block_bits
+        vals = (bs.block_ids[pos_a[blk_row]].astype(np.int64)
+                * bs.block_bits + bitpos)
+        rank_a = bs.index[pos_a[blk_row]] + np.asarray(ra_h)[:n]
+        rank_b = bs.index[pos_b[blk_row]] + np.asarray(rb_h)[:n]
     return (pair_id[blk_row], vals.astype(np.int32),
             rank_a.astype(np.int64), rank_b.astype(np.int64))
 

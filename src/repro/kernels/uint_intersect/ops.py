@@ -10,6 +10,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
+from repro import trace
 from repro.kernels.common import (LANE, SUBLANE, interpret_default,
                                   round_up)
 from repro.kernels.uint_intersect.kernel import uint_intersect_kernel
@@ -19,6 +20,7 @@ def uint_intersect_count(a, b, *, interpret=None):
     """Counts for already-padded batches a [P, LA], b [P, LB] (pad = -1)."""
     if interpret is None:
         interpret = interpret_default()
+    trace.upload(a, b)
     a = jnp.asarray(a, jnp.int32)
     b = jnp.asarray(b, jnp.int32)
     p, la = a.shape
@@ -114,7 +116,8 @@ def intersect_count_csr_batched(offsets, neighbors, u, v, *, interpret=None,
     la = int(max(1, deg[u].max()))
     lb = int(max(1, deg[v].max()))
     assert max(la, lb) <= max_len, "route long sets to the search path"
-    a = _gather_pad(offsets, neighbors, u, la)
-    b = _gather_pad(offsets, neighbors, v, lb)
+    with trace.span("eh.pairs.expand"):
+        a = _gather_pad(offsets, neighbors, u, la)
+        b = _gather_pad(offsets, neighbors, v, lb)
     return np.asarray(uint_intersect_count(a, b, interpret=interpret),
                       np.int64)

@@ -36,9 +36,11 @@ Per-tenant dispatch counters (``tenant.<t>.queries`` / ``.batches`` /
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import threading
 from collections import OrderedDict
 
+from repro import trace
 from repro.analysis.concurrency_lint import guarded_by
 from repro.core.backend import ExecBackend, make_backend
 from repro.core.engine import Engine, PreparedQuery, QueryResult
@@ -47,10 +49,13 @@ from repro.core.trie import Trie
 
 @dataclasses.dataclass
 class Ticket:
-    """Admission handle for one submitted query: filled by ``drain``."""
+    """Admission handle for one submitted query: filled by ``drain``.
+    ``seq`` numbers the server's tickets in admission order; the
+    ``eh.serve.drain`` span that answers a ticket names it."""
 
     tenant: str
     params: tuple[object, ...]
+    seq: int = 0
     result: QueryResult | None = None
     done: bool = False
 
@@ -182,6 +187,7 @@ class QueryServer:
         self._engines: dict[str, Engine] = {}
         self._prepared: dict[tuple[str, str], PreparedQuery] = {}
         self._queue: list[_Pending] = []
+        self._seq = itertools.count()
         self.counters: dict[str, int] = {}
 
     # ------------------------------------------------------------- tenants
@@ -254,7 +260,8 @@ class QueryServer:
         """Admit one query; execution is deferred to :meth:`drain` so
         same-shape requests can share a fused batched launch."""
         pq = self.prepare(tenant, text)
-        ticket = Ticket(tenant=tenant, params=pq._binding(params))
+        ticket = Ticket(tenant=tenant, params=pq._binding(params),
+                        seq=next(self._seq))
         with self._lock:
             self._queue.append(_Pending(ticket=ticket, prepared=pq))
         self._bump("queue.admitted")
@@ -270,24 +277,28 @@ class QueryServer:
         launch per same-shape chunk on the device backend, sequential
         parity loop elsewhere).  Tickets are filled in admission order.
         The queue is swapped out under the lock; execution happens
-        outside it so a long batch never blocks admission."""
+        outside it so a long batch never blocks admission.  The work runs
+        inside the root span ``eh.serve.drain``, whose ``tickets``
+        argument lists the ``seq`` of every ticket it answers."""
         with self._lock:
             queue, self._queue = self._queue, []
-        groups: OrderedDict[int, list[_Pending]] = OrderedDict()
-        for p in queue:
-            groups.setdefault(id(p.prepared), []).append(p)
-        for members in groups.values():
-            pq = members[0].prepared
-            tenant = members[0].ticket.tenant
-            self.store.touch(tenant)
-            results = pq.run_batch([p.ticket.params for p in members])
-            for p, res in zip(members, results):
-                p.ticket.result = res
-                p.ticket.done = True
-            self._bump(f"tenant.{tenant}.queries", len(members))
-            if len(members) > 1:
-                self._bump(f"tenant.{tenant}.batches")
-            self._evict_over_budget()
+        seqs = ",".join(str(p.ticket.seq) for p in queue)
+        with trace.span("eh.serve.drain", self.backend.stats, tickets=seqs):
+            groups: OrderedDict[int, list[_Pending]] = OrderedDict()
+            for p in queue:
+                groups.setdefault(id(p.prepared), []).append(p)
+            for members in groups.values():
+                pq = members[0].prepared
+                tenant = members[0].ticket.tenant
+                self.store.touch(tenant)
+                results = pq.run_batch([p.ticket.params for p in members])
+                for p, res in zip(members, results):
+                    p.ticket.result = res
+                    p.ticket.done = True
+                self._bump(f"tenant.{tenant}.queries", len(members))
+                if len(members) > 1:
+                    self._bump(f"tenant.{tenant}.batches")
+                self._evict_over_budget()
         self._bump("queue.drained", len(queue))
         return [p.ticket for p in queue]
 
