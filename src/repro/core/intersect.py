@@ -21,8 +21,10 @@ kernels' ``ref.py`` modules.
 
 The host arithmetic of the pair paths (expansion before a search, index
 arithmetic after it) runs inside ``eh.pairs.expand`` spans
-(``repro.trace``); each search counts its lanes (``pairs.searched``),
-its matches (``pairs.found``) and its operands (``upload.bytes``).
+(``repro.trace``); each search runs as many steps as the longest
+segment of its CSR needs and counts its lanes (``pairs.searched``), lanes
+times steps (``pairs.search_steps``), its matches (``pairs.found``) and
+its operands (``upload.bytes``).
 """
 from __future__ import annotations
 
@@ -67,6 +69,14 @@ def segment_searchsorted(values, lo, hi, queries, iters: int = 34):
     (the TPU adaptation of SIMDGalloping). Returns (pos, found) where ``pos``
     is the insertion point (absolute index into ``values``) and ``found`` says
     values[pos] == query (within the segment).
+
+    ``iters`` bounds the steps. A step leaves an open segment of length L
+    with at most ``floor(L / 2)`` (``mid`` halves it, and a step to the
+    right drops ``mid`` too), so after k steps at most ``floor(L / 2^k)``
+    remain, and ``L.bit_length()`` steps close it: every lane whose segment
+    is no longer than L has converged, and more steps change neither
+    ``pos`` nor ``found``. The default of 34 closes any int32 range;
+    the host pair paths pass the bound of the CSR they search.
     """
     values = jnp.asarray(values)
     size = values.shape[0]
@@ -92,14 +102,20 @@ def segment_searchsorted(values, lo, hi, queries, iters: int = 34):
     return lo_f, found
 
 
-def _search(values, lo, hi, queries):
-    """``segment_searchsorted`` for the host pair paths: counts the
-    operands it uploads and its lanes, and returns ``found`` on the host
-    (``pos`` stays on the device)."""
+def _search(values, offsets, lo, hi, queries):
+    """``segment_searchsorted`` for the host pair paths, whose segments
+    ``lo`` / ``hi`` all lie in the CSR ``offsets``: runs as many steps as
+    the bit length of the CSR's longest segment (one CSR, one bound, so a
+    repeated search compiles nothing new), counts the operands it uploads,
+    its lanes (``pairs.searched``), lanes times steps
+    (``pairs.search_steps``) and matches, and returns ``found`` on the
+    host (``pos`` stays on the device)."""
+    iters = max(1, int(np.diff(offsets).max(initial=0)).bit_length())
     trace.upload(values, lo, hi, queries)
-    pos, found = segment_searchsorted(values, lo, hi, queries)
+    pos, found = segment_searchsorted(values, lo, hi, queries, iters=iters)
     found = np.asarray(found)
     trace.add("pairs.searched", len(found))
+    trace.add("pairs.search_steps", len(found) * iters)
     trace.add("pairs.found", np.count_nonzero(found))
     return pos, found
 
@@ -159,7 +175,8 @@ def intersect_count_uint(offsets: np.ndarray, neighbors: np.ndarray,
         pair_id, _, q, lo, hi = _expand_smaller(offsets, neighbors, u, v)
     for s in range(0, len(pair_id), chunk):
         e = min(s + chunk, len(pair_id))
-        _, found = _search(values_dev, lo[s:e], hi[s:e], q[s:e])
+        _, found = _search(values_dev, offsets, lo[s:e], hi[s:e],
+                           q[s:e])
         with trace.span("eh.pairs.expand"):
             np.add.at(out, pair_id[s:e], found.astype(np.int64))
     return out
@@ -180,7 +197,7 @@ def intersect_pairs_uint(offsets: np.ndarray, neighbors: np.ndarray,
         swap = deg[u] > deg[v]
         pair_id, elem_idx, q, lo, hi = _expand_smaller(offsets, neighbors,
                                                        u, v)
-    pos, found = _search(neighbors, lo, hi, q)
+    pos, found = _search(neighbors, offsets, lo, hi, q)
     pos = np.asarray(pos)
     with trace.span("eh.pairs.expand"):
         keep = found
@@ -381,7 +398,7 @@ def uint_bitset_intersect_count(offsets, neighbors, u: np.ndarray,
         blk = (elems // bs.block_bits).astype(np.int32)
         lo = bs.offsets[b_slots][pair_id]
         hi = bs.offsets[b_slots + 1][pair_id]
-    pos, found = _search(bs.block_ids, lo, hi, blk)
+    pos, found = _search(bs.block_ids, bs.offsets, lo, hi, blk)
     pos = np.asarray(pos)
     with trace.span("eh.pairs.expand"):
         bit = elems % bs.block_bits

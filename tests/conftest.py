@@ -31,3 +31,21 @@ def brute_triangle_count(adj: np.ndarray) -> int:
     """Count undirected triangles by trace(A^3)/6."""
     a = adj.astype(np.int64)
     return int(np.trace(a @ a @ a) // 6)
+
+
+def hub_csr(n: int = 2048, hub_degree: int = 64, seed: int = 0):
+    """Directed CSR whose longest set, vertex 0's, has ``hub_degree``
+    elements spread over every 256-bit block of ``[0, n)``; vertices 1-199
+    hold up to 20 elements each, half drawn from the hub's set, and the
+    rest are empty."""
+    from repro.core.trie import CSRGraph
+    r = np.random.default_rng(seed)
+    hub = 1 + np.arange(hub_degree) * (n // hub_degree)
+    src, dst = [np.zeros(hub_degree, np.int64)], [hub]
+    for u in range(1, 200):
+        k = int(r.integers(0, 21))
+        pool = np.concatenate([hub, r.integers(0, n, hub_degree)])
+        nb = np.unique(r.choice(pool, k, replace=False))
+        src.append(np.full(len(nb), u))
+        dst.append(nb)
+    return CSRGraph.from_edges(np.concatenate(src), np.concatenate(dst), n=n)

@@ -1,8 +1,12 @@
 """Layout optimizer + set-intersection properties (paper §4), with
 hypothesis property tests on the core invariants."""
+from functools import partial
+
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
+
+from conftest import hub_csr
 
 from repro.core import intersect as I
 from repro.core.layouts import (HybridSetStore, decide_relation_level,
@@ -148,3 +152,96 @@ def test_uint_bitset_cross_layout(rng):
                                         bs.slot_of[v])
     want = I.intersect_count_uint_np(csr.offsets, csr.neighbors, u, v)
     np.testing.assert_array_equal(got, want)
+
+
+# ------------------------------------------- search bounded by its segments
+def _segments(k: int, longest: int):
+    """Segments of length 0, 1, 2^k - 1 and 2^k up to ``longest``, even
+    values, probed below, at, between and above their elements."""
+    lens = [n for n in (0, 1, 2**k - 1, 2**k, 2**k + 1) if n <= longest]
+    values, lo, hi, q = [], [], [], []
+    start = 0
+    for j, n in enumerate(lens):
+        seg = 1000 * (j + 1) + 2 * np.arange(n)
+        probes = np.concatenate([[seg[0] - 1 if n else 1000 * (j + 1)],
+                                 seg, seg + 1, [1000 * (j + 1) + 2 * n + 7]])
+        values.append(seg)
+        lo.append(np.full(len(probes), start))
+        hi.append(np.full(len(probes), start + n))
+        q.append(probes)
+        start += n
+    values = np.concatenate(values).astype(np.int32)
+    lo, hi = np.concatenate(lo), np.concatenate(hi)
+    q = np.concatenate(q).astype(np.int32)
+    pos, found = I.segment_searchsorted(values, lo, hi, q,
+                                        iters=max(lens).bit_length())
+    want_pos, want_found = I.segment_searchsorted_np(values, lo, hi, q)
+    return [(pos, want_pos), (found, want_found)]
+
+
+def _pairs_oracle(offsets, neighbors, u, v):
+    """Pair-major ``(pair_id, value, pos_u, pos_v)`` by np.intersect1d."""
+    out = [[], [], [], []]
+    for i, (a, b) in enumerate(zip(u, v)):
+        vals, ia, ib = np.intersect1d(
+            neighbors[offsets[a]:offsets[a + 1]],
+            neighbors[offsets[b]:offsets[b + 1]], return_indices=True)
+        for col, x in zip(out, (np.full(len(vals), i), vals,
+                                offsets[a] + ia, offsets[b] + ib)):
+            col.append(x)
+    return [np.concatenate(c) for c in out]
+
+
+def _callers(name: str):
+    """One ``_search`` caller on ``hub_csr``, whose longest set has 64
+    elements over 8 blocks: both bounds are powers of two."""
+    csr = hub_csr()
+    off, nb = csr.offsets, csr.neighbors
+    bs = I.build_blocked_bitset(off, nb, np.flatnonzero(csr.degrees > 0),
+                                csr.n, 256)
+    assert (np.diff(off).max(), np.diff(bs.offsets).max()) == (64, 8)
+    rng = np.random.default_rng(5)
+    u = np.concatenate([[0, 0, 7, 300], rng.integers(0, 260, 60)])
+    v = np.concatenate([[3, 0, 0, 0], rng.integers(0, 260, 60)])
+    # the bitset holds the non-empty sets only
+    in_bs = csr.degrees[v] > 0
+    if name.startswith("bitset"):
+        in_bs &= csr.degrees[u] > 0
+    if name != "intersect_count_uint" and name != "intersect_pairs_uint":
+        u, v = u[in_bs], v[in_bs]
+    if name == "intersect_count_uint":
+        got = I.intersect_count_uint(off, nb, u, v)
+    elif name == "bitset_intersect_count":
+        got = I.bitset_intersect_count(bs, bs.slot_of[u], bs.slot_of[v])
+    elif name == "uint_bitset_intersect_count":
+        got = I.uint_bitset_intersect_count(off, nb, u, bs, bs.slot_of[v])
+    else:
+        if name == "intersect_pairs_uint":
+            got = I.intersect_pairs_uint(off, nb, u, v)
+        else:
+            pid, vals, ra, rb = I.bitset_intersect_materialize(
+                bs, bs.slot_of[u], bs.slot_of[v])
+            got = (pid, vals, off[u[pid]] + ra, off[v[pid]] + rb)
+        return list(zip(got, _pairs_oracle(off, nb, u, v)))
+    return [(got, I.intersect_count_uint_np(off, nb, u, v))]
+
+
+BOUNDED_SEARCH_CASES = {
+    "segments-2^1": lambda: _segments(1, 2),
+    "segments-2^4": lambda: _segments(4, 16),
+    "segments-2^4+1": lambda: _segments(4, 17),
+    "segments-2^6+1": lambda: _segments(6, 65),
+    **{name: partial(_callers, name) for name in (
+        "intersect_count_uint", "intersect_pairs_uint",
+        "bitset_intersect_count", "bitset_intersect_materialize",
+        "uint_bitset_intersect_count")},
+}
+
+
+@pytest.mark.parametrize("case", list(BOUNDED_SEARCH_CASES))
+def test_search_bounded_by_the_longest_segment_is_exact(case):
+    """``bit_length(longest segment)`` steps give the same ``pos`` and
+    ``found`` as a full search, and every pair path that searches with
+    that bound equals its numpy oracle."""
+    for got, want in BOUNDED_SEARCH_CASES[case]():
+        np.testing.assert_array_equal(np.asarray(got), want)

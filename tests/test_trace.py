@@ -6,7 +6,8 @@
   open span;
 * **engine spans** — a device-backend triangle query records the layer
   spans, and the dispatch summary stays counts only;
-* **engine counters** — pair-search lanes, upload bytes, batch fill.
+* **engine counters** — pair-search lanes and steps, upload bytes,
+  batch fill.
 """
 import collections
 
@@ -15,10 +16,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import random_undirected_graph
+from conftest import hub_csr, random_undirected_graph
 from repro import trace
 from repro.core import workload as W
 from repro.core.engine import Engine
+from repro.core.layouts import HybridSetStore, decide_relation_level
 from repro.serve import QueryServer
 
 ANCHORED = "C(;w:long) :- R(0,y),S(y,z),T(0,z); w=<<COUNT(*)>>."
@@ -118,7 +120,29 @@ def test_pair_searches_count_lanes_and_matches(backend):
     eng.query(W.TRIANGLE_COUNT)
     st = eng.backend.stats
     assert st["pairs.searched"] >= st["pairs.found"] > 0, dict(st)
+    assert st["pairs.search_steps"] >= st["pairs.searched"]
+    assert (eng.backend.dispatch_summary()["pairs.search_steps"]
+            == st["pairs.search_steps"])
     assert st["upload.bytes"] > 0
+
+
+@pytest.mark.parametrize("cohort, steps", [("uint", 7), ("bitset", 4)])
+def test_pair_search_steps_follow_the_longest_segment(cohort, steps):
+    """Each lane runs bit_length(longest segment) steps: 64 CSR elements
+    for the uint cohort, 8 blocks for the bitset's block ids."""
+    csr = hub_csr()
+    store = HybridSetStore.build(csr,
+                                 decision=decide_relation_level(csr, cohort))
+    offsets = csr.offsets if cohort == "uint" else store.bitset.offsets
+    assert int(np.diff(offsets).max()).bit_length() == steps
+    rng = np.random.default_rng(2)
+    u = np.concatenate([[0], rng.integers(1, 200, 40)])
+    v = np.concatenate([[5], rng.integers(1, 200, 40)])
+    c = collections.Counter()
+    with trace.span("pairs", c):
+        store.intersect_count(u, v)
+    assert c["pairs.searched"] > 0
+    assert c["pairs.search_steps"] == c["pairs.searched"] * steps
 
 
 def test_device_uploads_count_trie_bytes():
