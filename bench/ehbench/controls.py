@@ -5,10 +5,9 @@ A hook is called with the engine object the loop built (an ``Engine``
 for ``repeat``, a ``QueryServer`` for ``open``) before its warm-up.
 
 * ``control``: the plain reference put in the program's place, with one
-  guarantee the configuration states broken.  ``ordered=False`` counts
-  each match once instead of every ordered match (what a
-  symmetry-breaking count gives); ``acc_dtype`` counts in a narrower
-  integer type than the engine's 32 bits.
+  guarantee the configuration states broken: the reference module's
+  ``CONTROLS`` names each such variant by the keywords it passes to the
+  module's ``reference`` (or ``per_vertex``).
 * ``stale``: a step that returns its state unchanged.  ``repeat``
   answers each query with the previous answer without running it;
   ``open`` returns from ``drain`` without running the queue.
@@ -22,35 +21,41 @@ import numpy as np
 
 
 class _Answer:
-    """Stands in for a ``QueryResult`` holding one count."""
+    """Stands in for a ``QueryResult``: one count, or a keyed answer
+    ``(keys, values)`` as a key column and its annotation."""
 
-    def __init__(self, count):
-        self.count = count
+    def __init__(self, value):
+        if isinstance(value, tuple):
+            keys, self.annotation = value
+            self.vars, self.columns = ("x",), {"x": keys}
+        else:
+            self.vars, self.columns = (), {}
+            self.annotation = np.asarray(value)
 
     def scalar(self):
-        return np.asarray(self.count)
+        return self.annotation
 
 
-def control(graph, ref, *, ordered: bool = True, acc_dtype=np.int64):
+def control(graph, ref, name: str):
+    opts = ref.CONTROLS[name]
+
     def hook(target):
         if hasattr(target, "submit"):
             def drain(srv=target):
                 queue, srv._queue = srv._queue, []
                 want = ref.per_vertex(graph, [p.ticket.params[0]
-                                              for p in queue],
-                                      acc_dtype=acc_dtype, ordered=ordered)
+                                              for p in queue], **opts)
                 for p in queue:
                     p.ticket.result = _Answer(want[p.ticket.params[0]])
                     p.ticket.done = True
                 return [p.ticket for p in queue]
             target.drain = drain
         else:
-            count = ref.reference(graph, acc_dtype=acc_dtype,
-                                  ordered=ordered)
+            value = ref.reference(graph, **opts)
 
             def query(_text, eng=target):
                 eng.backend.stats["pipeline.launches"] += 1
-                return _Answer(count)
+                return _Answer(value)
             target.query = query
     return hook
 
@@ -86,15 +91,18 @@ def half_batch(target):
 
 
 def altered(_target):
+    """Patches ``QueryResult``'s constructor, where every path of the
+    engine (joins, counts, recursive fixpoints) builds its answer; returns
+    the real one."""
     from repro.core import engine
 
-    real = engine.QueryResult.from_gj
+    real = engine.QueryResult.__init__
 
-    def from_gj(res):
-        out = real(res)
-        out.annotation = np.asarray(out.annotation) + 1
-        return out
-    engine.QueryResult.from_gj = staticmethod(from_gj)
+    def init(self, vars, columns, annotation):
+        real(self, vars, columns, annotation)
+        if annotation is not None:
+            self.annotation = np.asarray(annotation) + 1
+    engine.QueryResult.__init__ = init
     return real
 
 

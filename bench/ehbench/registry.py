@@ -6,7 +6,12 @@
 * a metric (end-to-end or per-layer) is read by
   ``bench/metrics/<name>.py``, whose ``read(run)`` returns a number, or
   None where the run holds nothing for it to read;
-* a plain reference is ``bench/references/<name>.py``.
+* a plain reference is ``bench/references/<name>.py``.  Its
+  ``reference(graph)`` and ``answer(result)`` give a count, compared
+  exactly, or a keyed answer ``(keys, values)``; a module of keyed
+  answers also defines ``compare(got, want)``, the entries that differ
+  under its own ``RTOL`` and ``ATOL``.  Its ``CONTROLS`` names the
+  variants of ``reference`` that break a guarantee, by their keywords.
 
 A later cell adds files and entries; nothing here changes.
 """

@@ -11,6 +11,13 @@ from __future__ import annotations
 
 import numpy as np
 
+# The broken guarantees a control puts in the program's place: each pair
+# {y, z} counted once; counts in 16 bits; counts in the engine's own 32
+# bits, the nearest type below the ``long`` the program declares.
+CONTROLS = {"unordered": {"ordered": False},
+            "int16": {"acc_dtype": np.int16},
+            "int32": {"acc_dtype": np.int32}}
+
 
 def per_vertex(graph, vertices, acc_dtype=np.int64,
                ordered: bool = True) -> dict[int, int]:

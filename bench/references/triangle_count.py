@@ -11,6 +11,13 @@ import numpy as np
 import scipy.sparse as sp
 
 BLOCK_ROWS = 1 << 14
+# The broken guarantees a control puts in the program's place: each
+# triangle counted once, as a symmetry-breaking count does; counts in 16
+# bits; counts in the engine's own 32 bits, the nearest type below the
+# ``long`` the program declares.
+CONTROLS = {"unordered": {"ordered": False},
+            "int16": {"acc_dtype": np.int16},
+            "int32": {"acc_dtype": np.int32}}
 
 
 def adjacency(graph) -> sp.csr_matrix:

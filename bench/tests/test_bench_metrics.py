@@ -21,7 +21,7 @@ def traced():
             ev("jit__bag_program_batch(2)", 25, 50),
             ev("jit_bitset_and_popcount_kernel(3)", 80, 84),
             ev("jit_uint_intersect_kernel(4)", 85, 86)]
-    spans = [ev("bench.window", 0, 100)]
+    spans = [ev("bench.window", 0, 100), ev("eh.pairs", 0, 20)]
     return T.Trace(ops=ops, modules=mods, spans=spans, devices=1)
 
 
@@ -31,7 +31,12 @@ def record(loop, trace=None):
         latencies_s=[0.1, 0.2, 0.3, 0.4, 1.0], waits_s=[0.0, 0.1, 0.2],
         counters={"compiles": 2, "compiles.first_serve": 5,
                   "pipeline.batched_queries": 9,
-                  "pipeline.batched_launches": 3},
+                  "pipeline.batched_launches": 3,
+                  "span.eh.pairs.ns": 8_000 * MS,
+                  "span.eh.pairs.expand.ns": 2_000 * MS,
+                  "span.eh.land.ns": 1_200 * MS, "span.eh.plan.ns": 4 * MS,
+                  "upload.bytes": 800_000_000, "pairs.searched": 1000,
+                  "pairs.found": 250},
         trace=trace, trace_window=(0, 100 * MS) if trace else None,
         traced_units=2 if trace else 0)
 

@@ -52,14 +52,15 @@ def read(name, run):
 
 
 def test_every_new_metric_is_declared_once():
-    """The serving cell's new metrics are declared; those of the
-    triangle cell wait for the benchmark's own test record to carry
-    their spans and counters (``test_bench_metrics.py`` checks that
-    every declared ``tri`` metric reads on it)."""
-    declared = [m["name"] for m in BENCH["per_layer"]]
-    for name in NEW:
-        want = 1 if name.endswith(".serve") else 0
-        assert declared.count(name) == want, name
+    """Each reader of the engine's spans and counters is declared once,
+    for the one cell whose runs it reads (``test_bench_metrics.py``
+    checks that every declared metric reads on its synthetic record)."""
+    declared = [m for m in BENCH["per_layer"] if m["name"] in NEW]
+    assert sorted(m["name"] for m in declared) == sorted(NEW)
+    for m in declared:
+        cell = ("serve-zipf.g500-s17" if m["name"].endswith(".serve")
+                else "tri.g500-s14")
+        assert m["workloads"] == [cell], m["name"]
 
 
 @pytest.mark.parametrize("name", NEW)
