@@ -574,37 +574,46 @@ class Engine:
         plan = self._compile(sub)
         res = self._execute(plan, encode=encode)
         keyvars = tuple(rule.head.keyvars)
-        if not keyvars:
-            count = np.asarray(res.num_rows, dtype=np.int64)
-            value = eval_expr(rule.agg_expr, count, self.catalog.scalars)
-            return QueryResult((), {}, np.asarray(value))
-        keys = np.stack([np.asarray(res.columns[v]) for v in keyvars], axis=1)
-        uniq, inv = np.unique(keys, axis=0, return_inverse=True)
-        counts = np.bincount(inv, minlength=len(uniq))
-        value = eval_expr(rule.agg_expr, counts, self.catalog.scalars)
-        cols = {v: uniq[:, i].astype(np.int32) for i, v in enumerate(keyvars)}
-        return QueryResult(keyvars, cols, np.asarray(value))
+        with trace.span("eh.aggregate", self.backend.stats):
+            if not keyvars:
+                count = np.asarray(res.num_rows, dtype=np.int64)
+                value = eval_expr(rule.agg_expr, count, self.catalog.scalars)
+                return QueryResult((), {}, np.asarray(value))
+            keys = np.stack([np.asarray(res.columns[v]) for v in keyvars],
+                            axis=1)
+            uniq, inv = np.unique(keys, axis=0, return_inverse=True)
+            counts = np.bincount(inv, minlength=len(uniq))
+            value = eval_expr(rule.agg_expr, counts, self.catalog.scalars)
+            cols = {v: uniq[:, i].astype(np.int32)
+                    for i, v in enumerate(keyvars)}
+            return QueryResult(keyvars, cols, np.asarray(value))
 
     def _materialize_head(self, rule: Rule, res: QueryResult):
-        name = rule.head.rel
-        if not rule.head.keyvars:
-            if res.annotation is not None:
-                self.catalog.scalars[name] = np.asarray(res.annotation).item() \
-                    if np.asarray(res.annotation).ndim == 0 else res.annotation
-            return
-        cols = [res.columns[v] for v in rule.head.keyvars]
-        t = Trie.build(name, tuple(rule.head.keyvars), cols,
-                       annotation=res.annotation)
-        self.catalog.add(name, t)
+        """Store a rule's result under its head: a scalar, or a trie
+        built from the head's key columns.  Runs inside the span
+        ``eh.materialize``."""
+        with trace.span("eh.materialize", self.backend.stats):
+            name = rule.head.rel
+            if not rule.head.keyvars:
+                if res.annotation is not None:
+                    ann = np.asarray(res.annotation)
+                    self.catalog.scalars[name] = (ann.item() if ann.ndim == 0
+                                                  else res.annotation)
+                return
+            cols = [res.columns[v] for v in rule.head.keyvars]
+            t = Trie.build(name, tuple(rule.head.keyvars), cols,
+                           annotation=res.annotation)
+            self.catalog.add(name, t)
 
     # ------------------------------------------------------------ recursion
     def _eval_recursive(self, rule: Rule) -> QueryResult:
-        agg = rule.agg
-        sr = AGG_TO_SEMIRING[agg.op] if agg is not None else None
-        seminaive = sr in (MIN_PLUS, MAX_MIN)
-        if seminaive:
-            return self._seminaive(rule, sr)
-        return self._naive(rule)
+        """A Kleene-star rule, inside the span ``eh.recursion``."""
+        with trace.span("eh.recursion", self.backend.stats):
+            agg = rule.agg
+            sr = AGG_TO_SEMIRING[agg.op] if agg is not None else None
+            if sr in (MIN_PLUS, MAX_MIN):
+                return self._seminaive(rule, sr)
+            return self._naive(rule)
 
     # ----------------------------------------- device-resident fast path
     def _spmv_shape(self, rule: Rule):
@@ -659,9 +668,15 @@ class Engine:
         return self.backend.name == "device" and self.device_recursion
 
     def _record_device_recursion(self, rule: Rule, strategy: str,
-                                 rounds: int):
-        self.backend.stats["recursion.device_fixpoints"] += 1
-        self.backend.stats["recursion.device_rounds"] += int(rounds)
+                                 rounds: int, edges: int, keys: int):
+        """Count one device fixpoint: its rounds, and the edges and keys
+        each round of its loop reads (``recursion.edge_rounds`` /
+        ``recursion.key_rounds``, rounds times each)."""
+        stats = self.backend.stats
+        stats["recursion.device_fixpoints"] += 1
+        stats["recursion.device_rounds"] += int(rounds)
+        stats["recursion.edge_rounds"] += int(rounds) * int(edges)
+        stats["recursion.key_rounds"] += int(rounds) * int(keys)
         self._program_metadata.append({
             "head": rule.head.rel,
             "recursion": {"mode": "device", "strategy": strategy,
@@ -697,21 +712,21 @@ class Engine:
             # a base tuple annotated with the semiring zero would be
             # indistinguishable from "underived" in the masked state
             return None
-        src, dst, eann = self.catalog.get(e.rel).edge_view()
-        gather_v, scatter_v = (src, dst) if e.vars == (r, h) else (dst, src)
-        n = int(max(keys0.max(initial=0),
-                    gather_v.max(initial=0), scatter_v.max(initial=0))) + 1
+        with trace.span("eh.recursion.prepare", self.backend.stats):
+            src, dst, eann = self.catalog.get(e.rel).edge_view()
+            gather_v, scatter_v = ((src, dst) if e.vars == (r, h)
+                                   else (dst, src))
+            n = int(max(keys0.max(initial=0), gather_v.max(initial=0),
+                        scatter_v.max(initial=0))) + 1
         max_rounds = (int(rule.recursion.value)
                       if rule.recursion.kind == "iterations" else 1 << 30)
         keys, ann, rounds = recursion_mod.seminaive_device_fixpoint(
             sr, apply_expr, gather_v, scatter_v, eann, n, keys0, ann0,
             max_rounds)
-        self._record_device_recursion(rule, "seminaive", rounds)
-        keyvars = tuple(rule.head.keyvars)
-        keys32 = keys.astype(np.int32)
-        self.catalog.add(name, Trie.build(name, keyvars, [keys32],
-                                          annotation=ann))
-        return QueryResult(keyvars, {keyvars[0]: keys32}, ann)
+        # the loop's state spans the whole vertex domain [0, n)
+        self._record_device_recursion(rule, "seminaive", rounds,
+                                      len(gather_v), n)
+        return self._device_recursion_result(rule, keys, ann)
 
     def _naive_device(self, rule: Rule, prev_keys: np.ndarray,
                       iters: Optional[int], tol: Optional[float],
@@ -741,8 +756,6 @@ class Engine:
             return None
         keys = np.asarray(prev_keys, dtype=np.int64)
         ann0 = np.asarray(base.annotation, dtype=np.float64)
-        src, dst, eann = self.catalog.get(e.rel).edge_view()
-        gather_v, scatter_v = (src, dst) if e.vars == (r, h) else (dst, src)
 
         def positions(sorted_keys, queries):
             if len(sorted_keys) == 0:
@@ -752,41 +765,51 @@ class Engine:
             pos = np.clip(pos, 0, len(sorted_keys) - 1)
             return pos, sorted_keys[pos] == queries
 
-        out_idx, valid = positions(keys, scatter_v)
-        rec_idx, ok = positions(keys, gather_v)
-        valid = valid & ok
-        # ⊗-factors in body-atom order (exactly the fold's mul order)
-        factor_kinds: List[str] = []
-        gathers: List[np.ndarray] = []
-        for a in rule.body:
-            if self.catalog.resolve(a.rel) == self.catalog.resolve(name):
-                factor_kinds.append("rec")
-            elif len(a.terms) == 2:
-                if eann is not None:
-                    factor_kinds.append("static")
-                    gathers.append(np.asarray(eann))
-            else:
-                t = self.catalog.get(a.rel)
-                upos, ok = positions(
-                    t.levels[0].values.astype(np.int64), gather_v)
-                valid = valid & ok
-                if t.annotation is not None:
-                    factor_kinds.append("static")
-                    gathers.append(np.asarray(t.annotation)[upos])
-        out_idx = out_idx[valid]
-        rec_idx = rec_idx[valid]
-        factor_anns = [g[valid] for g in gathers]
+        with trace.span("eh.recursion.prepare", self.backend.stats):
+            src, dst, eann = self.catalog.get(e.rel).edge_view()
+            gather_v, scatter_v = ((src, dst) if e.vars == (r, h)
+                                   else (dst, src))
+            out_idx, valid = positions(keys, scatter_v)
+            rec_idx, ok = positions(keys, gather_v)
+            valid = valid & ok
+            # ⊗-factors in body-atom order (exactly the fold's mul order)
+            factor_kinds: List[str] = []
+            gathers: List[np.ndarray] = []
+            for a in rule.body:
+                if self.catalog.resolve(a.rel) == self.catalog.resolve(name):
+                    factor_kinds.append("rec")
+                elif len(a.terms) == 2:
+                    if eann is not None:
+                        factor_kinds.append("static")
+                        gathers.append(np.asarray(eann))
+                else:
+                    t = self.catalog.get(a.rel)
+                    upos, ok = positions(
+                        t.levels[0].values.astype(np.int64), gather_v)
+                    valid = valid & ok
+                    if t.annotation is not None:
+                        factor_kinds.append("static")
+                        gathers.append(np.asarray(t.annotation)[upos])
+            out_idx = out_idx[valid]
+            rec_idx = rec_idx[valid]
+            factor_anns = [g[valid] for g in gathers]
         if iters is None and tol is None:
             iters = max_iters   # bare-star naive: fixed round budget
         ann, rounds = recursion_mod.naive_device_fixpoint(
             sr, apply_expr, out_idx, rec_idx, tuple(factor_kinds),
             factor_anns, len(keys), ann0, iters, tol, max_iters)
-        self._record_device_recursion(rule, "naive", rounds)
+        self._record_device_recursion(rule, "naive", rounds, len(out_idx),
+                                      len(keys))
+        return self._device_recursion_result(rule, keys, ann)
+
+    def _device_recursion_result(self, rule: Rule, keys: np.ndarray,
+                                 ann: np.ndarray) -> QueryResult:
+        """A device fixpoint's keys and annotations as the head's
+        result, materialized under the head."""
         keyvars = tuple(rule.head.keyvars)
-        keys32 = keys.astype(np.int32)
-        self.catalog.add(name, Trie.build(name, keyvars, [keys32],
-                                          annotation=ann))
-        return QueryResult(keyvars, {keyvars[0]: keys32}, ann)
+        res = QueryResult(keyvars, {keyvars[0]: keys.astype(np.int32)}, ann)
+        self._materialize_head(rule, res)
+        return res
 
     def _naive(self, rule: Rule) -> QueryResult:
         """Naive recursion: re-evaluate the body against the full current

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import trace
 from repro.core.semiring import MIN_PLUS, SUM_F32, Semiring
 from repro.core.trie import CSRGraph
 from repro.kernels.common import audit_avals, host_get
@@ -269,14 +270,18 @@ def seminaive_device_fixpoint(sr: Semiring, apply_expr: ExprFn,
                               max_rounds: int):
     """Host entry point: densify the initial relation over [0, n), run the
     jitted while-loop, and sparsify the result back to (keys, ann).
-    Exactly ONE host sync happens, after the loop."""
+    Exactly ONE host sync happens, after the loop.  The operands are put
+    on the device inside the span ``eh.recursion.prepare`` and counted
+    in ``upload.bytes``."""
     dt = jnp.zeros((), sr.dtype).dtype
-    state0 = jnp.full((n,), sr.zero, dtype=dt)
-    state0 = state0.at[jnp.asarray(keys0)].set(
-        jnp.asarray(ann0).astype(dt))
-    frontier0 = jnp.zeros((n,), jnp.bool_).at[jnp.asarray(keys0)].set(True)
-    ea = None if edge_ann is None else jnp.asarray(edge_ann).astype(dt)
-    g, sc = jnp.asarray(gather), jnp.asarray(scatter)
+    with trace.span("eh.recursion.prepare"):
+        trace.upload(keys0, ann0, gather, scatter, edge_ann)
+        k0 = jnp.asarray(keys0)
+        state0 = jnp.full((n,), sr.zero, dtype=dt)
+        state0 = state0.at[k0].set(jnp.asarray(ann0).astype(dt))
+        frontier0 = jnp.zeros((n,), jnp.bool_).at[k0].set(True)
+        ea = None if edge_ann is None else jnp.asarray(edge_ann).astype(dt)
+        g, sc = jnp.asarray(gather), jnp.asarray(scatter)
     if AUDIT_LOG is not None:
         AUDIT_LOG.append(
             ("seminaive", "seminaive", sr, apply_expr, int(max_rounds),
@@ -347,11 +352,15 @@ def naive_device_fixpoint(sr: Semiring, apply_expr: ExprFn,
                           factor_anns: List[np.ndarray], k: int,
                           ann0: np.ndarray, iters: Optional[int],
                           tol: Optional[float], max_rounds: int):
-    """Host entry point for the device naive loop; ONE final sync."""
+    """Host entry point for the device naive loop; ONE final sync.  The
+    operands are put on the device inside the span
+    ``eh.recursion.prepare`` and counted in ``upload.bytes``."""
     dt = jnp.zeros((), sr.dtype).dtype
-    anns = tuple(jnp.asarray(a).astype(dt) for a in factor_anns)
-    oi, ri = jnp.asarray(out_idx), jnp.asarray(rec_idx)
-    a0 = jnp.asarray(ann0).astype(dt)
+    with trace.span("eh.recursion.prepare"):
+        trace.upload(out_idx, rec_idx, ann0, *factor_anns)
+        anns = tuple(jnp.asarray(a).astype(dt) for a in factor_anns)
+        oi, ri = jnp.asarray(out_idx), jnp.asarray(rec_idx)
+        a0 = jnp.asarray(ann0).astype(dt)
     if AUDIT_LOG is not None:
         AUDIT_LOG.append(
             ("naive", "naive", sr, apply_expr, iters, tol,

@@ -7,9 +7,16 @@
 * **engine spans** — a device-backend triangle query records the layer
   spans, and the dispatch summary stays counts only;
 * **engine counters** — pair-search lanes and steps, upload bytes,
-  batch fill.
+  batch fill;
+* **recursion** — a device-backend PageRank records the recursion,
+  aggregation and materialisation spans, counts the edges and keys its
+  fixpoint's rounds read and the bytes of its operands, and agrees with
+  the benchmark's plain reference.
 """
 import collections
+import importlib.util
+import pathlib
+import types
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +27,7 @@ from conftest import hub_csr, random_undirected_graph
 from repro import trace
 from repro.core import workload as W
 from repro.core.engine import Engine
+from repro.core import recursion
 from repro.core.layouts import HybridSetStore, decide_relation_level
 from repro.serve import QueryServer
 
@@ -177,3 +185,122 @@ def test_drain_spans_and_batch_fill():
     slots, rows = st["pipeline.batched_slots"], st["pipeline.batched_rows"]
     assert slots % 4 == 0 and 0 < rows <= slots, dict(st)
     assert not [k for k in srv.dispatch_summary() if k.startswith("span.")]
+
+
+# ------------------------------------------------------------------ recursion
+RECURSION_SPANS = ("eh.recursion", "eh.recursion.prepare", "eh.aggregate",
+                   "eh.materialize")
+
+
+def pagerank_reference():
+    """The benchmark's plain PageRank reference (``bench/references``)."""
+    path = (pathlib.Path(__file__).resolve().parents[1] / "bench"
+            / "references" / "pagerank.py")
+    spec = importlib.util.spec_from_file_location("pagerank_reference", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def csr_graph(adj):
+    """The reference's view of a graph: vertex count, CSR offsets and
+    sorted neighbours."""
+    src, dst = np.nonzero(adj)
+    offsets = np.zeros(len(adj) + 1, np.int64)
+    np.cumsum(np.bincount(src, minlength=len(adj)), out=offsets[1:])
+    return types.SimpleNamespace(n=len(adj), offsets=offsets,
+                                 neighbors=dst.astype(np.int64),
+                                 degrees=np.diff(offsets))
+
+
+def pagerank_engine(monkeypatch, n=60, p=0.1, seed=5):
+    """A device engine over a random graph whose fixpoints record the
+    upload bytes counted while each ran."""
+    src, dst, adj = random_undirected_graph(n, p, seed)
+    eng = Engine(backend="device")
+    eng.load_edges("Edge", src, dst)
+    fixpoint_uploads = []
+    real = recursion.naive_device_fixpoint
+
+    def spy(*args, **kw):
+        before = eng.backend.stats["upload.bytes"]
+        out = real(*args, **kw)
+        fixpoint_uploads.append(eng.backend.stats["upload.bytes"] - before)
+        return out
+
+    monkeypatch.setattr(recursion, "naive_device_fixpoint", spy)
+    return eng, adj, fixpoint_uploads
+
+
+def test_device_pagerank_records_its_recursion_layers(monkeypatch):
+    eng, adj, _ = pagerank_engine(monkeypatch)
+    eng.query(W.pagerank_program(5))
+    st = eng.backend.stats
+    for name in RECURSION_SPANS:
+        assert st[f"span.{name}.calls"] > 0, (name, dict(st))
+        assert 0 <= st[f"span.{name}.self_ns"] <= st[f"span.{name}.ns"]
+    assert st["span.eh.recursion.calls"] == 1
+    assert st["span.eh.recursion.ns"] >= st["span.eh.recursion.prepare.ns"]
+    # N and InvDeg are count-distinct rules; every keyed head is a trie
+    assert st["span.eh.aggregate.calls"] == 2
+    assert st["recursion.host_rounds"] == 0
+    assert not [k for k in eng.dispatch_summary() if k.startswith("span.")]
+
+
+def test_device_pagerank_counts_edge_and_key_rounds(monkeypatch):
+    eng, adj, _ = pagerank_engine(monkeypatch)
+    eng.query(W.pagerank_program(5))
+    st = eng.backend.stats
+    edges, keys = int(adj.sum()), int(np.count_nonzero(adj.any(axis=1)))
+    assert st["recursion.device_fixpoints"] == 1
+    assert st["recursion.device_rounds"] == 5
+    assert st["recursion.edge_rounds"] == st["recursion.device_rounds"] * edges
+    assert st["recursion.key_rounds"] == st["recursion.device_rounds"] * keys
+
+
+def test_device_pagerank_uploads_count_the_fixpoint_operands(monkeypatch):
+    """The loop's operands reach ``upload.bytes``: a key position for
+    each end of every edge, the inverse degree gathered per edge, and
+    one starting rank per key, 4 bytes each on the device."""
+    eng, adj, fixpoint_uploads = pagerank_engine(monkeypatch)
+    eng.query(W.pagerank_program(5))
+    edges, keys = int(adj.sum()), int(np.count_nonzero(adj.any(axis=1)))
+    assert fixpoint_uploads == [4 * (3 * edges + keys)]
+    assert eng.backend.stats["upload.bytes"] > fixpoint_uploads[0]
+
+
+def test_device_pagerank_agrees_with_the_plain_reference(monkeypatch):
+    ref = pagerank_reference()
+    eng, adj, _ = pagerank_engine(monkeypatch, n=80, p=0.08, seed=9)
+    got = ref.answer(eng.query(W.pagerank_program(5)))
+    want = ref.reference(csr_graph(adj))
+    assert len(want[0]) > 0
+    assert ref.compare(got, want) == 0
+
+
+def test_device_sssp_counts_its_seminaive_rounds():
+    """The seminaive loop reads every edge and the whole vertex domain
+    each round, and its operands are counted in ``upload.bytes``."""
+    src, dst, adj = random_undirected_graph(40, 0.1, seed=4)
+    eng = Engine(backend="device")
+    eng.load_edges("Edge", src, dst)
+    eng.query(W.sssp_program(int(src[0])))
+    st = eng.backend.stats
+    assert st["recursion.device_fixpoints"] == 1
+    assert st["recursion.host_rounds"] == 0
+    rounds = st["recursion.device_rounds"]
+    assert rounds > 0
+    assert st["recursion.edge_rounds"] == rounds * len(src)
+    n = int(max(src.max(), dst.max())) + 1
+    assert st["recursion.key_rounds"] == rounds * n
+    for name in ("eh.recursion", "eh.recursion.prepare", "eh.materialize"):
+        assert st[f"span.{name}.calls"] > 0, name
+
+
+def test_triangle_query_records_no_recursion_span():
+    eng = make_engine("device")
+    eng.query(W.TRIANGLE_COUNT)
+    st = eng.backend.stats
+    assert not [k for k in st if k.startswith("span.eh.recursion")]
+    assert "recursion.edge_rounds" not in st
+    assert st["span.eh.materialize.calls"] == 1
