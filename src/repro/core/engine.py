@@ -46,7 +46,7 @@ from repro.core.datalog import (AggRef, Num, Param, Rule, ScalarRef, Var,
                                 eval_expr, parse)
 from repro.core.executor import (BagResultCache, Catalog, Executor,
                                  apply_expr)
-from repro.core.gj import GenericJoin, GJResult, run_batched
+from repro.core.gj import GenericJoin, GJResult, group_rows, run_batched
 from repro.core.semiring import AGG_TO_SEMIRING, MAX_MIN, MIN_PLUS, SUM_F32
 from repro.core.statistics import StatisticsCatalog
 from repro.core.trie import Trie
@@ -579,13 +579,11 @@ class Engine:
                 count = np.asarray(res.num_rows, dtype=np.int64)
                 value = eval_expr(rule.agg_expr, count, self.catalog.scalars)
                 return QueryResult((), {}, np.asarray(value))
-            keys = np.stack([np.asarray(res.columns[v]) for v in keyvars],
-                            axis=1)
-            uniq, inv = np.unique(keys, axis=0, return_inverse=True)
-            counts = np.bincount(inv, minlength=len(uniq))
+            uniq, inv = group_rows([res.columns[v] for v in keyvars],
+                                   self.backend.stats)
+            counts = np.bincount(inv, minlength=len(uniq[0]))
             value = eval_expr(rule.agg_expr, counts, self.catalog.scalars)
-            cols = {v: uniq[:, i].astype(np.int32)
-                    for i, v in enumerate(keyvars)}
+            cols = {v: u.astype(np.int32) for v, u in zip(keyvars, uniq)}
             return QueryResult(keyvars, cols, np.asarray(value))
 
     def _materialize_head(self, rule: Rule, res: QueryResult):

@@ -750,16 +750,74 @@ class GenericJoin:
             total = np.asarray(sr.segment_reduce(
                 np.asarray(ann), np.zeros(F, np.int32), 1))[0]
             return GJResult((), {}, np.asarray(total))
-        key_cols = [cols[k] for k in self.output_vars]
-        stacked = np.stack(key_cols, axis=1)
-        uniq, inv = np.unique(stacked, axis=0, return_inverse=True)
-        out_cols = {k: uniq[:, i].astype(np.int32)
-                    for i, k in enumerate(self.output_vars)}
+        uniq, inv = group_rows([cols[k] for k in self.output_vars],
+                               self.backend.stats)
+        out_cols = {k: u.astype(np.int32)
+                    for k, u in zip(self.output_vars, uniq)}
         if sr is None:
             return GJResult(self.output_vars, out_cols, None)
         folded = np.asarray(sr.segment_reduce(np.asarray(ann),
-                                              inv.astype(np.int32), len(uniq)))
+                                              inv.astype(np.int32),
+                                              len(uniq[0])))
         return GJResult(self.output_vars, out_cols, folded)
+
+
+# ------------------------------------------------------------------ grouping
+def _run_starts(cols: List[np.ndarray]) -> Optional[np.ndarray]:
+    """Where rows already in non-decreasing lexicographic order start a
+    new run: a mask of length m, True at row 0 and at every row that
+    differs from the one before.  None where the rows are out of order.
+    One pass per column, folded from the last column to the first: a
+    pair of neighbouring rows is ordered by its first differing column."""
+    ordered = differ = None
+    for c in reversed(cols):
+        prev, cur = c[:-1], c[1:]
+        if ordered is None:
+            ordered, differ = cur >= prev, cur != prev
+        else:
+            ne = cur != prev
+            ordered = np.where(ne, cur > prev, ordered)
+            differ |= ne
+    if not ordered.all():
+        return None
+    return np.concatenate(([True], differ))
+
+
+def group_rows(columns: Sequence[np.ndarray], stats=None
+               ) -> Tuple[List[np.ndarray], np.ndarray]:
+    """Group the rows whose key columns are ``columns`` (1-D, equal
+    length m, at least one): the distinct rows in ascending lexicographic
+    order, one array per column in its own dtype, and the 1-D inverse
+    mapping each row to its distinct row.  Exactly what
+    ``np.unique(np.stack(columns, 1), axis=0, return_inverse=True)``
+    gives, without its sort of the rows as opaque records.
+
+    Rows already in order (a landed frontier comes out in trie order)
+    are grouped by their run boundaries with no sort; one unsorted
+    column takes the 1-D ``np.unique``; several take ``np.lexsort``.
+    Runs inside the span ``eh.group`` and counts ``group.rows`` and, for
+    rows grouped without a sort, ``group.presorted_rows``."""
+    with trace.span("eh.group", stats):
+        cols = [np.asarray(c) for c in columns]
+        m = len(cols[0])
+        trace.add("group.rows", m)
+        if m == 0:
+            return [c[:0] for c in cols], np.zeros(0, np.intp)
+        starts = _run_starts(cols)
+        if starts is not None:
+            trace.add("group.presorted_rows", m)
+            first = np.flatnonzero(starts)
+            return [c[first] for c in cols], np.cumsum(starts) - 1
+        if len(cols) == 1:
+            uniq, inv = np.unique(cols[0], return_inverse=True)
+            return [uniq], inv
+        order = np.lexsort(cols[::-1])
+        cols = [c[order] for c in cols]
+        starts = _run_starts(cols)
+        first = np.flatnonzero(starts)
+        inv = np.empty(m, np.intp)
+        inv[order] = np.cumsum(starts) - 1
+        return [c[first] for c in cols], inv
 
 
 # ------------------------------------------------------------- batched entry

@@ -11,7 +11,10 @@
 * **recursion** — a device-backend PageRank records the recursion,
   aggregation and materialisation spans, counts the edges and keys its
   fixpoint's rounds read and the bytes of its operands, and agrees with
-  the benchmark's plain reference.
+  the benchmark's plain reference;
+* **grouping** — PageRank's key rows reach ``eh.group`` in order, a
+  triangle count groups nothing, and count-distinct programs whose rows
+  come out of order agree across backends.
 """
 import collections
 import importlib.util
@@ -27,7 +30,7 @@ from conftest import hub_csr, random_undirected_graph
 from repro import trace
 from repro.core import workload as W
 from repro.core.engine import Engine
-from repro.core import recursion
+from repro.core import gj, recursion
 from repro.core.layouts import HybridSetStore, decide_relation_level
 from repro.serve import QueryServer
 
@@ -304,3 +307,61 @@ def test_triangle_query_records_no_recursion_span():
     assert not [k for k in st if k.startswith("span.eh.recursion")]
     assert "recursion.edge_rounds" not in st
     assert st["span.eh.materialize.calls"] == 1
+
+
+# ------------------------------------------------------------------- grouping
+def test_device_pagerank_groups_presorted_rows_inside_its_layers(monkeypatch):
+    """Two groupings a query: ``N``'s body projected to ``x`` (under
+    ``eh.finalize``) and ``InvDeg``'s count-distinct (under
+    ``eh.aggregate``); the base ``PageRank`` body reuses ``N``'s bag.
+    Both key columns come out in trie order, so neither sorts."""
+    eng, _, _ = pagerank_engine(monkeypatch)
+    parents = []
+    real = gj._run_starts
+
+    def spy(cols):
+        parents.append([s.name for s in trace._stack()][-2:])
+        return real(cols)
+
+    monkeypatch.setattr(gj, "_run_starts", spy)
+    eng.query(W.pagerank_program(5))
+    st = eng.backend.stats
+    assert sorted(parents) == [["eh.aggregate", "eh.group"],
+                               ["eh.finalize", "eh.group"]]
+    assert st["span.eh.group.calls"] == 2
+    assert st["group.rows"] > 0
+    assert st["group.presorted_rows"] == st["group.rows"]
+
+
+def test_triangle_query_records_no_grouping():
+    eng = make_engine("device")
+    eng.query(W.TRIANGLE_COUNT)
+    st = eng.backend.stats
+    assert not [k for k in st if k.startswith(("span.eh.group", "group."))]
+
+
+@pytest.mark.parametrize("program", [
+    "D(z;w:int) :- Edge(x,z); w=<<COUNT(x)>>.",
+    "P(x,y;w:int) :- Edge(x,z),Edge(z,y); w=<<COUNT(z)>>."])
+def test_unordered_count_distinct_agrees_across_backends(program):
+    """Key rows that reach the grouping out of trie order (one column:
+    the 1-D unique; two: the lexsort) give the numpy backend's answer on
+    the device."""
+    results = {}
+    for backend in ("numpy", "device"):
+        eng = make_engine(backend)
+        res = eng.query(program)
+        st = eng.backend.stats
+        assert st["span.eh.group.calls"] == 1
+        assert st["group.presorted_rows"] < st["group.rows"]
+        results[backend] = res
+    want, got = results["numpy"], results["device"]
+    assert got.vars == want.vars
+    for v in want.vars:
+        assert np.array_equal(got.columns[v], want.columns[v])
+    assert np.array_equal(got.annotation, want.annotation)
+    if want.vars == ("z",):
+        src, dst, _ = random_undirected_graph(40, 0.3, 3)
+        indeg = np.bincount(dst)
+        assert np.array_equal(want.columns["z"], np.flatnonzero(indeg))
+        assert np.array_equal(want.annotation, indeg[indeg > 0])
